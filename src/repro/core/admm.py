@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .kernels_math import KernelSpec, center_gram, gram, psd_jitter_eigh, resolve_gamma
-from .rho import RhoSchedule, auto_rho
+from .rho import SAFETY, RhoSchedule, auto_rho
 from .solver import admm_step, dense_parts, init_state, lagrangian
 from .topology import Graph
 
@@ -316,15 +316,15 @@ def run_admm(setup: DkpcaSetup, n_iters: int = 30,
     """Run Alg. 1 (whole history in one jitted scan; see
     ``repro.core.solver.run_chunked`` for the resumable chunked driver).
 
-    rho2 defaults to the paper's warm-up schedule (10 -> 50 -> 100); pass
-    ``RhoSchedule.constant(auto_rho(...))`` for the Theorem-2 regime.
+    rho2 defaults to the Theorem-2 constant (``theorem2_rho``); pass
+    ``RhoSchedule()`` for the paper's §6.1 warm-up (10 -> 50 -> 100).
     ``init`` defaults to the local-solution z warm-start (the measured fix
     for the slow low-m transient — docs/ADMM_CONVERGENCE.md §Ablations);
     ``init="paper"`` restores the paper's Gaussian initialization.
     ``project="sphere"`` enables the beyond-paper renormalization that
     removes the degenerate z=0 attractor."""
     if rho2 is None:
-        rho2 = RhoSchedule()
+        rho2 = RhoSchedule.constant(theorem2_rho(setup))
     if alpha0 is None:
         alpha0 = initial_alpha(setup, init, seed)
     ts = np.arange(n_iters)
@@ -343,7 +343,7 @@ def run_admm(setup: DkpcaSetup, n_iters: int = 30,
                        primal_residual=rhist, rho_hist=rho2_arr)
 
 
-def theorem2_rho(setup: DkpcaSetup, safety: float = 1.05) -> float:
+def theorem2_rho(setup: DkpcaSetup, safety: float = SAFETY) -> float:
     """Assumption-2-satisfying constant rho for this setup."""
     degrees = np.asarray(jnp.sum(setup.mask, axis=1))
     return auto_rho(np.asarray(setup.lam), degrees, safety)

@@ -46,9 +46,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .admm import initial_alpha, local_solution_alpha  # noqa: F401
 from .kernels_math import KernelSpec, gram, psd_jitter_eigh, resolve_gamma
-from .rho import RhoSchedule
+from .rho import SAFETY, RhoSchedule, assumption2_rho
 from .solver import AdmmState, RingComm, SolverOps, admm_step
-from ..distributed.compat import pvary, shard_map
 from ..obs.comm import CommLedger
 from .topology import ring_shifts
 
@@ -102,6 +101,10 @@ def dkpca_distributed(
     communication and warm-starts z at the pooled local components (the
     measured m=24 transient fix, docs/ADMM_CONVERGENCE.md); "paper" is the
     paper's unnormalized Gaussian.
+    rho2: neighbor rho schedule; None (default) is the Theorem-2 constant
+    of the dense drivers (``repro.core.admm.theorem2_rho``), formed inside
+    the node program from each node's own spectrum and one scalar pmax at
+    setup.
     b0/t0: resume a run from iteration ``t0`` with duals ``b0`` (J, N, S)
     — pass the previous call's ``result.b``/``result.alpha``; the rho2
     schedule is evaluated at the global iteration indices [t0, t0+n_iters).
@@ -125,8 +128,7 @@ def dkpca_distributed(
     jj, n, m = x_nodes.shape
     assert jj == j_nodes, (jj, j_nodes)
     assert center in ("global", "none")
-    if rho2 is None:
-        rho2 = RhoSchedule()
+    theorem2 = rho2 is None
     if gamma is None:
         g = resolve_gamma(spec, x_nodes.reshape(jj * n, m))
     else:
@@ -143,8 +145,11 @@ def dkpca_distributed(
                                        jnp.float32)
         else:
             raise ValueError(f"unknown init {init!r}")
-    rho2_arr = jnp.asarray([rho2.at(t) for t in range(t0, t0 + n_iters)],
-                           jnp.float32)
+    if theorem2:
+        rho2_arr = jnp.zeros((n_iters,), jnp.float32)  # set in the program
+    else:
+        rho2_arr = jnp.asarray(
+            [rho2.at(t) for t in range(t0, t0 + n_iters)], jnp.float32)
     rho_self = float(rho1) if include_self else 0.0
 
     offsets = ring_shifts(hops)                 # [-r..-1, 1..r]
@@ -165,7 +170,7 @@ def dkpca_distributed(
                  offsets=tuple(offsets), rev_static=tuple(rev_static),
                  s_slots=s_slots, spec=spec, center=center,
                  rho_self=rho_self, include_self=include_self,
-                 project=project, n_iters=n_iters, t0=t0,
+                 theorem2=theorem2, project=project, n_iters=n_iters, t0=t0,
                  local_init=local_init, use_pallas=use_pallas,
                  message_dtype=message_dtype, unroll_iters=unroll_iters,
                  ledger=ledger)
@@ -180,7 +185,7 @@ def dkpca_distributed(
             (lm.shape, (n_iters, jj, s_slots))
         in_specs.append(P(None, axis_names, None))
         args.append(lm)
-    shmap = shard_map(
+    shmap = jax.shard_map(
         fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(axis_names, None), P(axis_names, None, None),
@@ -199,7 +204,7 @@ def dkpca_distributed(
 
 def _node_fn(x_blk, a_blk, b_blk, g, rho2_arr, *extra, axes, j_nodes,
              offsets, rev_static, s_slots, spec, center, rho_self,
-             include_self, project, n_iters, t0, local_init=False,
+             include_self, theorem2, project, n_iters, t0, local_init=False,
              use_pallas=False, message_dtype=None, unroll_iters=False,
              ledger=None):
     """Per-node SPMD program. x_blk: (1, N, M); a_blk: (1, N);
@@ -214,6 +219,12 @@ def _node_fn(x_blk, a_blk, b_blk, g, rho2_arr, *extra, axes, j_nodes,
     b0 = b_blk[0]
     n = x.shape[0]
     lm = extra[0][:, 0] if extra else None               # (n_iters, S)
+
+    def varying_zeros(shape):
+        # fresh zeros are replicated; a scan carry that starts from them
+        # must have the type of the per-node values: varying over the axes
+        return jax.lax.pcast(jnp.zeros(shape, jnp.float32), axes,
+                             to="varying")
 
     def gram_fn(xa, xb):
         if use_pallas:
@@ -246,8 +257,8 @@ def _node_fn(x_blk, a_blk, b_blk, g, rho2_arr, *extra, axes, j_nodes,
             rot = _ring_recv(rot, axes, 1, j_nodes)
             return (rot, macc, mubar), None
 
-        zero_n = pvary(jnp.zeros((n,), jnp.float32), axes)
-        zero_s = pvary(jnp.zeros((), jnp.float32), axes)
+        zero_n = varying_zeros((n,))
+        zero_s = varying_zeros(())
         (_, macc, mubar), _ = jax.lax.scan(
             sweep, (x, zero_n, zero_s), None, length=j_nodes)
         m_own = macc / (j_nodes * n)                       # m(x) for own rows
@@ -278,6 +289,13 @@ def _node_fn(x_blk, a_blk, b_blk, g, rho2_arr, *extra, axes, j_nodes,
     maskf = jnp.concatenate(
         [jnp.full((1,), 1.0 if include_self else 0.0, jnp.float32),
          jnp.ones((n_nbr,), jnp.float32)])
+    if theorem2:
+        # theorem2_rho of the dense drivers: the largest per-node
+        # Assumption-2 bound over the network, one scalar pmax
+        bound = assumption2_rho(lam, jnp.sum(maskf))
+        rho2_arr = jnp.full_like(rho2_arr, jax.lax.pmax(bound, axes) * SAFETY)
+        if ledger is not None:
+            ledger.record_collective(jnp.dtype(jnp.float32).itemsize)
     ops = SolverOps(kcross=kcross, k=k_loc, lam=lam, vec=vec, mask=maskf)
     comm = RingComm(axes, j_nodes, offsets, rev_static,
                     message_dtype=message_dtype, ledger=ledger)
@@ -296,11 +314,9 @@ def _node_fn(x_blk, a_blk, b_blk, g, rho2_arr, *extra, axes, j_nodes,
         return new, (new.alpha, res, new.znorm2)
 
     state0 = AdmmState(
-        alpha=alpha, b=b0, g=pvary(jnp.zeros((n, s_slots), jnp.float32),
-                                   axes),
-        znorm2=pvary(jnp.zeros((), jnp.float32), axes),
-        t=jnp.asarray(t0, jnp.int32),
-        rho=pvary(jnp.zeros((s_slots,), jnp.float32), axes))
+        alpha=alpha, b=b0, g=varying_zeros((n, s_slots)),
+        znorm2=varying_zeros(()), t=jnp.asarray(t0, jnp.int32),
+        rho=varying_zeros((s_slots,)))
     final, (ahist, rhist, znhist) = jax.lax.scan(
         iteration, state0, jnp.arange(n_iters), unroll=unroll_iters)
     return (final.alpha[None], final.b[None], ahist[:, None, :], rhist,

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernels_math import KernelSpec, gram, resolve_gamma
+from .kernels_math import HIGHEST, KernelSpec, gram, resolve_gamma
 
 # Shape conventions used throughout this module:
 #   B = query batch, M = features, L = support rows, C = components,
@@ -136,7 +136,7 @@ def from_dual(x_train: jax.Array, alpha: jax.Array, spec: KernelSpec,
         mu_bar = jnp.mean(k_raw)
         alpha_sum = jnp.sum(alpha, axis=0)                # (C,)
         row_mean_coef = -alpha_sum
-        bias = mu_bar * alpha_sum - m @ alpha
+        bias = mu_bar * alpha_sum - jnp.matmul(m, alpha, precision=HIGHEST)
         stats = dict(k_row_mean=m, k_grand_mean=mu_bar)
     else:
         row_mean_coef = jnp.zeros((c,), jnp.float32)
@@ -263,7 +263,8 @@ def refresh_coefficients(model: Union[FittedKpca, "ShardedFittedKpca"],
     if model.k_row_mean is not None:
         alpha_sum = jnp.sum(alpha, axis=0)
         row_mean_coef = -alpha_sum
-        bias = model.k_grand_mean * alpha_sum - model.k_row_mean @ alpha
+        bias = (model.k_grand_mean * alpha_sum
+                - jnp.matmul(model.k_row_mean, alpha, precision=HIGHEST))
     else:
         if bool(np.any(np.asarray(model.row_mean_coef))) or \
                 bool(np.any(np.asarray(model.bias))):
@@ -304,7 +305,7 @@ def project(model: FittedKpca, x_query: jax.Array,
                           row_mean_coef=model.row_mean_coef, bias=model.bias,
                           gamma=model.gamma, interpret=interpret)
     k = gram(model.spec, x_query, model.x_support, gamma=model.gamma)
-    return (k @ model.coefs
+    return (jnp.matmul(k, model.coefs, precision=HIGHEST)
             + jnp.mean(k, axis=1, keepdims=True) * model.row_mean_coef[None]
             + model.bias[None, :])
 
@@ -594,7 +595,8 @@ def _sharded_centering(model: ShardedFittedKpca, coefs_pad: jax.Array
     rows are zero in both the coefficients and the cache, so plain sums
     over (S, Lp) ARE the true-row sums."""
     alpha_sum = jnp.sum(coefs_pad, axis=(0, 1))          # (C,)
-    m_dot = jnp.einsum("sl,slc->c", model.k_row_mean, coefs_pad)
+    m_dot = jnp.einsum("sl,slc->c", model.k_row_mean, coefs_pad,
+                       precision=HIGHEST)
     return -alpha_sum, model.k_grand_mean * alpha_sum - m_dot
 
 

@@ -501,10 +501,11 @@ def resolve_rho2(rho2, setup) -> Callable[[int], float]:
     """Normalize a rho2 policy to a host-side ``t -> float``.
 
     Accepts a ``RhoSchedule``, the string "theorem2" (Assumption-2 constant
-    for this setup), a plain number, or any callable ``t -> rho``.
+    for this setup; also what None means), a plain number, or any callable
+    ``t -> rho``.
     """
     if rho2 is None:
-        rho2 = RhoSchedule()
+        rho2 = "theorem2"
     if isinstance(rho2, str):
         if rho2 != "theorem2":
             raise ValueError(f"unknown rho2 policy {rho2!r}")
@@ -552,8 +553,8 @@ def run_chunked(setup, n_iters: int = 30, chunk: int = 10,
         completed by a resumed ``state``).
       chunk: iterations per jitted chunk (the yield granularity).
       rho1: self-slot rho (ignored when setup.include_self is False).
-      rho2: neighbor rho policy — ``RhoSchedule`` (default: paper warm-up),
-        "theorem2", a constant, or a callable ``t -> rho``; evaluated
+      rho2: neighbor rho policy — ``RhoSchedule``, "theorem2" (the
+        default), a constant, or a callable ``t -> rho``; evaluated
         host-side at chunk boundaries, so switching policy mid-run between
         driver invocations is well-defined (the warm (alpha, B) state
         carries the z warm-start across the switch).

@@ -23,8 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _admm_kernel(v_ref, invd_ref, k_ref, b_ref, g_ref, rho_ref,
@@ -63,7 +62,7 @@ def admm_local_update(v: jax.Array, inv_den: jax.Array, k: jax.Array,
         out_specs=[whole((n, 1)), whole((n, s))],
         out_shape=[jax.ShapeDtypeStruct((j, n, 1), jnp.float32),
                    jax.ShapeDtypeStruct((j, n, s), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(v, inv_den, k, b, g, rho_slots)

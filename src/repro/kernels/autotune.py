@@ -6,10 +6,12 @@ tiling (128x128x512, centering 256). That is a fine default on TPU-sized
 problems and provably NOT optimal everywhere else — tile choice is a
 hardware/shape question, so it is answered by measurement:
 
-  * a candidate grid per op, filtered by legality for the concrete padded
-    problem (sublane multiples of 8, lane multiples of 128, no tile wider
-    than the padded axis — anything larger is the same program after the
-    wrappers' auto-shrink);
+  * a candidate grid per op, filtered by what Mosaic compiles for the
+    concrete padded problem: sublane (row) tiles in multiples of 8; lane
+    tiles — the output's last axis, which also carries the (1, k) norm
+    row — in multiples of 128 or the whole padded axis; feature tiles in
+    multiples of 128; and no tile wider than the padded axis (anything
+    larger is the same program after the wrappers' auto-shrink);
   * each candidate timed best-of-``k`` with ``jax.block_until_ready`` on
     the actual output (compile excluded by an untimed warmup call);
   * winners persisted to a JSON **tile table** keyed by
@@ -183,39 +185,53 @@ def _round_up(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
 
 
+_SUBLANE_TILES = (8, 16, 32, 64, 128, 256)
+_LANE_TILES = (128, 256)
+_FEATURE_TILES = (128, 256, 512)
+
+
+def _tiles(choices: Sequence[int], padded: int) -> List[int]:
+    """Tiles from ``choices`` that fit ``padded``; below the smallest
+    choice the whole padded axis is the one tile (always legal)."""
+    return [b for b in choices if b <= padded] or [padded]
+
+
 def gram_candidates(n: int, k: int, m: int) -> List[Dict[str, int]]:
     """Legal (block_n, block_k, block_m) grid for an (n, m) x (k, m) gram.
 
-    Legality: row tiles are multiples of 8 (sublane), feature tiles
-    multiples of 128 (lane); tiles beyond the padded axis are dropped —
-    the wrapper's auto-shrink maps them to the same program as the
-    axis-sized tile, so timing them twice is pure waste.
+    Legality: row tiles are multiples of 8 (sublane); column tiles are the
+    output's lane axis, so multiples of 128 (or the whole axis below 128);
+    feature tiles multiples of 128. Tiles beyond the padded axis are
+    dropped — the wrapper's auto-shrink maps them to the same program as
+    the axis-sized tile, so timing them twice is pure waste.
     """
     np_, kp, mp = _round_up(n, 8), _round_up(k, 8), _round_up(m, 128)
-    bns = [b for b in (8, 16, 32, 64, 128, 256) if b <= np_] or [np_]
-    bks = [b for b in (8, 16, 32, 64, 128, 256) if b <= kp] or [kp]
-    bms = [b for b in (128, 256, 512) if b <= mp] or [mp]
+    bns = _tiles(_SUBLANE_TILES, np_)
+    bks = _tiles(_LANE_TILES, kp)
+    bms = _tiles(_FEATURE_TILES, mp)
     return [{"block_n": bn, "block_k": bk, "block_m": bm}
             for bn in bns for bk in bks for bm in bms]
 
 
 def project_candidates(b: int, l: int, m: int) -> List[Dict[str, int]]:
     """Legal (block_q, block_l, block_m) grid for a (b, m) query batch
-    against an (l, m) support set (same legality rules as gram)."""
+    against an (l, m) support set (same legality rules as gram: the
+    support tile is the lane axis of the kernel block and of the norm
+    row)."""
     bp, lp, mp = _round_up(b, 8), _round_up(l, 8), _round_up(m, 128)
-    bqs = [x for x in (8, 16, 32, 64, 128, 256) if x <= bp] or [bp]
-    bls = [x for x in (8, 16, 32, 64, 128, 256) if x <= lp] or [lp]
-    bms = [x for x in (128, 256, 512) if x <= mp] or [mp]
+    bqs = _tiles(_SUBLANE_TILES, bp)
+    bls = _tiles(_LANE_TILES, lp)
+    bms = _tiles(_FEATURE_TILES, mp)
     return [{"block_q": bq, "block_l": bl, "block_m": bm}
             for bq in bqs for bl in bls for bm in bms]
 
 
 def centering_candidates(n: int, m: int) -> List[Dict[str, int]]:
     """Legal square-ish block grid for centering an (n, m) kernel matrix
-    (one knob: the wrapper derives row/col tiles from it)."""
+    (one knob: the wrapper derives row/col tiles from it, and the column
+    tile is a lane axis, so the knob is a multiple of 128)."""
     np_ = _round_up(n, 8)
-    return [{"block": b} for b in (64, 128, 256, 512)
-            if b <= max(np_, 128)] or [{"block": np_}]
+    return [{"block": b} for b in (128, 256, 512) if b <= max(np_, 128)]
 
 
 # ---- measurement ----------------------------------------------------------

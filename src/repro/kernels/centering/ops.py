@@ -30,8 +30,8 @@ def center_op(k: jax.Array, block: Optional[int] = None,
     bn = min(block, _round_up(n, 8))
     bk = min(block, _round_up(m, 128))
     kp = _pad_to(_pad_to(kf, bn, 0), bk, 1)
-    rp = _pad_to(row, bn, 0)
-    cp = _pad_to(col, bk, 0)
+    rp = _pad_to(row, bn, 0)[:, None]
+    cp = _pad_to(col, bk, 0)[None, :]
     out = center_tiles(kp, rp, cp, tot, block_n=bn, block_k=bk,
                        interpret=interpret)
     return out[:n, :m]

@@ -12,6 +12,14 @@ Grid: (n/bn, k/bk, m/bm), dimension_semantics = (parallel, parallel,
 arbitrary). Block shapes default to 128x128x512 (MXU lane/sublane aligned,
 ~0.5 MB per operand tile in fp32 — three tiles + output fit well within the
 ~16 MB VMEM budget with double buffering).
+
+Operand layout (Mosaic tiles VMEM as (8, 128) float32 blocks and refuses
+1-D blocks): the per-row norms arrive as a (n, 1) column and the per-column
+norms as a (1, k) row, so they broadcast against the (bn, bk) tile without
+a relayout; ``gamma`` is a scalar in SMEM. ``bk`` is therefore a lane
+dimension: a multiple of 128 or the whole padded axis. The contraction runs
+at ``Precision.HIGHEST``: the RBF epilogue forms ||x||^2 + ||y||^2 - 2 x.y,
+which cancels, and a single bf16 pass would lose most of the distance.
 """
 
 from __future__ import annotations
@@ -21,8 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gram_kernel(sx_ref, sy_ref, gamma_ref, x_ref, y_ref, o_ref, *,
@@ -38,16 +45,16 @@ def _gram_kernel(sx_ref, sy_ref, gamma_ref, x_ref, y_ref, o_ref, *,
     x = x_ref[...].astype(jnp.float32)          # (bn, bm)
     y = y_ref[...].astype(jnp.float32)          # (bk, bm)
     o_ref[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)     # (bn, bk)
 
     @pl.when(mb == n_m_blocks - 1)
     def _epilogue():
         acc = o_ref[...]
         if kind == "rbf":
-            sx = sx_ref[...].astype(jnp.float32)    # (bn,)
-            sy = sy_ref[...].astype(jnp.float32)    # (bk,)
-            d2 = sx[:, None] + sy[None, :] - 2.0 * acc
+            sx = sx_ref[...].astype(jnp.float32)    # (bn, 1)
+            sy = sy_ref[...].astype(jnp.float32)    # (1, bk)
+            d2 = sx + sy - 2.0 * acc
             d2 = jnp.maximum(d2, 0.0)
             o_ref[...] = jnp.exp(-gamma_ref[0] * d2)
         else:
@@ -58,7 +65,7 @@ def _gram_kernel(sx_ref, sy_ref, gamma_ref, x_ref, y_ref, o_ref, *,
                 # sx/sy hold the *self-kernel* values for linear/poly.
                 sx = sx_ref[...].astype(jnp.float32)
                 sy = sy_ref[...].astype(jnp.float32)
-                denom = jnp.maximum(sx[:, None] * sy[None, :], 1e-12)
+                denom = jnp.maximum(sx * sy, 1e-12)
                 k = k * jax.lax.rsqrt(denom)
             o_ref[...] = k
 
@@ -73,7 +80,8 @@ def gram_tiles(x: jax.Array, y: jax.Array, sx: jax.Array, sy: jax.Array,
                block_n: int = 128, block_k: int = 128, block_m: int = 512,
                interpret: bool = False) -> jax.Array:
     """Tiled Gram matrix. Shapes must be pre-padded to block multiples:
-    x (n, m), y (k, m), sx (n,), sy (k,) -> (n, k) float32."""
+    x (n, m), y (k, m), sx (n, 1), sy (1, k), gamma (1,) -> (n, k)
+    float32."""
     n, m = x.shape
     k = y.shape[0]
     assert n % block_n == 0 and k % block_k == 0 and m % block_m == 0, \
@@ -89,15 +97,15 @@ def gram_tiles(x: jax.Array, y: jax.Array, sx: jax.Array, sy: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j, b: (i,)),         # sx
-            pl.BlockSpec((block_k,), lambda i, j, b: (j,)),         # sy
-            pl.BlockSpec((1,), lambda i, j, b: (0,)),               # gamma
+            pl.BlockSpec((block_n, 1), lambda i, j, b: (i, 0)),     # sx
+            pl.BlockSpec((1, block_k), lambda i, j, b: (0, j)),     # sy
+            pl.BlockSpec(memory_space=pltpu.SMEM),                  # gamma
             pl.BlockSpec((block_n, block_m), lambda i, j, b: (i, b)),
             pl.BlockSpec((block_k, block_m), lambda i, j, b: (j, b)),
         ],
         out_specs=pl.BlockSpec((block_n, block_k), lambda i, j, b: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(sx, sy, gamma, x, y)

@@ -57,8 +57,8 @@ def gram_op(spec: KernelSpec, x: jax.Array, y: Optional[jax.Array] = None,
     bm = min(block_m, _round_up(x.shape[1], 128))
     xp = _pad_to(_pad_to(x, bm, 1), bn, 0)
     yp = _pad_to(_pad_to(y, bm, 1), bk, 0)
-    sxp = _pad_to(sx, bn, 0)
-    syp = _pad_to(sy, bk, 0)
+    sxp = _pad_to(sx, bn, 0)[:, None]
+    syp = _pad_to(sy, bk, 0)[None, :]
     out = gram_tiles(xp, yp, sxp, syp, jnp.reshape(g, (1,)).astype(jnp.float32),
                      kind=spec.kind, degree=spec.degree, coef=spec.coef,
                      scale=spec.scale, normalize=spec.normalize,

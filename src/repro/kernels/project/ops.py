@@ -71,8 +71,8 @@ def _prepare_operands(spec: KernelSpec, x_query: jax.Array,
 
     xq = _pad_to(_pad_to(x_query, bm, 1), bq, 0)
     xs = _pad_to(_pad_to(x_support, bm, 1), bl, 0)
-    sqp = _pad_to(sq, bq, 0)
-    ssp = _pad_to(ss, bl, 0)
+    sqp = _pad_to(sq, bq, 0)[:, None]
+    ssp = _pad_to(ss, bl, 0)[None, :]
     return xq, xs, sqp, ssp, g, bq, bl, bm
 
 
@@ -133,8 +133,8 @@ def project_op(spec: KernelSpec, x_query: jax.Array, x_support: jax.Array,
     ones = jnp.ones((l, 1), jnp.float32)
     a_ext = jnp.concatenate([coefs.astype(jnp.float32), ones], axis=1)
     a_ext = _pad_to(_pad_to(a_ext, cp, 1), bl, 0)
-    c_ext = _pad_to(row_mean_coef.astype(jnp.float32), cp, 0)
-    b_ext = _pad_to(bias.astype(jnp.float32), cp, 0)
+    c_ext = _pad_to(row_mean_coef.astype(jnp.float32), cp, 0)[None, :]
+    b_ext = _pad_to(bias.astype(jnp.float32), cp, 0)[None, :]
 
     out = project_tiles(
         xq, xs, a_ext, sqp, ssp,
@@ -190,7 +190,7 @@ def project_partial_op(spec: KernelSpec, x_query: jax.Array,
         spec, x_query, x_support, gamma, block_q, block_l, block_m)
     cp = _round_up(cp1, 128)
     a_ext = _pad_to(_pad_to(coefs_ext.astype(jnp.float32), cp, 1), bl, 0)
-    zeros = jnp.zeros((cp,), jnp.float32)
+    zeros = jnp.zeros((1, cp), jnp.float32)
 
     # row_mean_coef/bias are all-zero, so the kernel's in-tile centering
     # epilogue is the identity and every output column comes out raw.

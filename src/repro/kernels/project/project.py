@@ -18,6 +18,14 @@ no second pass or extra scratch is needed.
 Grid: (B/bq, L/bl, M/bm), dimension_semantics = (parallel, arbitrary,
 arbitrary) — the output block for a fixed q is revisited across the l/m
 axes. Defaults 128x128x512 match the gram kernel's MXU-aligned tiling.
+
+Operand layout, as in the gram kernel: query norms are a (B, 1) column,
+support norms a (1, L) row, the centering vectors (1, CP) rows and the
+scalars (gamma, 1/L) live in SMEM; ``bl`` is a lane dimension (a multiple
+of 128 or the whole padded support axis). The ones-column is read back
+with a lane mask rather than a one-lane slice. Both dots run at
+``Precision.HIGHEST`` (the distance expansion cancels; see the gram
+kernel).
 """
 
 from __future__ import annotations
@@ -29,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _project_kernel(sq_ref, ss_ref, gamma_ref, invl_ref, c_ref, b_ref,
@@ -51,16 +59,16 @@ def _project_kernel(sq_ref, ss_ref, gamma_ref, invl_ref, c_ref, b_ref,
     xq = xq_ref[...].astype(jnp.float32)            # (bq, bm)
     xs = xs_ref[...].astype(jnp.float32)            # (bl, bm)
     acc_ref[...] += jax.lax.dot_general(
-        xq, xs, (((1,), (1,)), ((), ())),
+        xq, xs, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)         # (bq, bl)
 
     @pl.when(mb == n_m_blocks - 1)
     def _kernel_epilogue_and_matvec():
         acc = acc_ref[...]
-        sq = sq_ref[...].astype(jnp.float32)        # (bq,)
-        ss = ss_ref[...].astype(jnp.float32)        # (bl,)
+        sq = sq_ref[...].astype(jnp.float32)        # (bq, 1)
+        ss = ss_ref[...].astype(jnp.float32)        # (1, bl)
         if kind == "rbf":
-            d2 = jnp.maximum(sq[:, None] + ss[None, :] - 2.0 * acc, 0.0)
+            d2 = jnp.maximum(sq + ss - 2.0 * acc, 0.0)
             k = jnp.exp(-gamma_ref[0] * d2)
         else:
             k = acc * scale
@@ -68,9 +76,10 @@ def _project_kernel(sq_ref, ss_ref, gamma_ref, invl_ref, c_ref, b_ref,
                 k = (k + coef) ** degree
             if normalize:
                 # sq/ss hold the *self-kernel* values for linear/poly.
-                denom = jnp.maximum(sq[:, None] * ss[None, :], 1e-12)
+                denom = jnp.maximum(sq * ss, 1e-12)
                 k = k * jax.lax.rsqrt(denom)
         o_ref[...] += jnp.dot(k, a_ref[...].astype(jnp.float32),
+                              precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
 
     @pl.when((lb == n_l_blocks - 1) & (mb == n_m_blocks - 1))
@@ -80,9 +89,11 @@ def _project_kernel(sq_ref, ss_ref, gamma_ref, invl_ref, c_ref, b_ref,
         # it accumulated the row-sums of K; turn them into the centering
         # term. c/b are zero there, so the column itself stays harmless
         # (the wrapper slices it off).
-        kmean = scores[:, sum_col] * invl_ref[0]    # (bq,)
-        o_ref[...] = (scores + kmean[:, None] * c_ref[...][None, :]
-                      + b_ref[...][None, :])
+        lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        ksum = jnp.sum(jnp.where(lane == sum_col, scores, 0.0), axis=1,
+                       keepdims=True)               # (bq, 1)
+        kmean = ksum * invl_ref[0]
+        o_ref[...] = scores + kmean * c_ref[...] + b_ref[...]
 
 
 @functools.partial(
@@ -99,8 +110,8 @@ def project_tiles(xq: jax.Array, xs: jax.Array, a_ext: jax.Array,
     """Fused projection over pre-padded operands.
 
     xq (B, M) queries; xs (L, M) support; a_ext (L, CP) coefficients with
-    the ones-column at ``sum_col``; sq (B,), ss (L,) sq-norms (RBF) or
-    self-kernels; gamma (1,); inv_l (1,) = 1/L_true; c_ext, b_ext (CP,).
+    the ones-column at ``sum_col``; sq (B, 1), ss (1, L) sq-norms (RBF) or
+    self-kernels; gamma (1,); inv_l (1,) = 1/L_true; c_ext, b_ext (1, CP).
     Returns (B, CP) float32 scores.
     """
     bq_n, m = xq.shape
@@ -121,12 +132,12 @@ def project_tiles(xq: jax.Array, xs: jax.Array, a_ext: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda i, j, b: (i,)),          # sq
-            pl.BlockSpec((block_l,), lambda i, j, b: (j,)),          # ss
-            pl.BlockSpec((1,), lambda i, j, b: (0,)),                # gamma
-            pl.BlockSpec((1,), lambda i, j, b: (0,)),                # inv_l
-            pl.BlockSpec((cp,), lambda i, j, b: (0,)),               # c_ext
-            pl.BlockSpec((cp,), lambda i, j, b: (0,)),               # b_ext
+            pl.BlockSpec((block_q, 1), lambda i, j, b: (i, 0)),      # sq
+            pl.BlockSpec((1, block_l), lambda i, j, b: (0, j)),      # ss
+            pl.BlockSpec(memory_space=pltpu.SMEM),                   # gamma
+            pl.BlockSpec(memory_space=pltpu.SMEM),                   # inv_l
+            pl.BlockSpec((1, cp), lambda i, j, b: (0, 0)),           # c_ext
+            pl.BlockSpec((1, cp), lambda i, j, b: (0, 0)),           # b_ext
             pl.BlockSpec((block_q, block_m), lambda i, j, b: (i, b)),
             pl.BlockSpec((block_l, block_m), lambda i, j, b: (j, b)),
             pl.BlockSpec((block_l, cp), lambda i, j, b: (j, 0)),     # a_ext
@@ -137,7 +148,7 @@ def project_tiles(xq: jax.Array, xs: jax.Array, a_ext: jax.Array,
             # persists across the sequential l/m axes for a fixed q block
             pltpu.VMEM((block_q, block_l), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(sq, ss, gamma, inv_l, c_ext, b_ext, xq, xs, a_ext)

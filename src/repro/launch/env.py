@@ -20,6 +20,11 @@ explicit ``EnvConfig`` fields win over them):
   * ``REPRO_HOST_DEVICES``  -> ``--xla_force_host_platform_device_count``
   * ``REPRO_TILE_TABLE``    -> consumed by ``repro.kernels.autotune``
     directly; listed here because this layer is where deployments set it.
+  * ``JAX_COMPILATION_CACHE_DIR`` -> JAX's persistent compilation cache.
+    Left as it is when set; otherwise ``apply_from_environ`` points it at
+    ``<checkout>/.jax_cache``. The path is part of the cache's key, so it
+    is fixed: every process of one checkout finds what an earlier one
+    compiled.
 """
 
 from __future__ import annotations
@@ -39,6 +44,12 @@ GPU_XLA_FLAGS: Tuple[str, ...] = (
 )
 
 _TRUTHY = ("1", "true", "yes", "on")
+
+CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/launch/env.py -> the checkout root.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 @dataclasses.dataclass
@@ -102,7 +113,9 @@ def apply(cfg: EnvConfig) -> Dict[str, str]:
 
 def apply_from_environ() -> Dict[str, str]:
     """``apply`` driven purely by ``REPRO_*`` variables — the one-liner
-    for launchers whose argparse runs after jax-importing imports."""
+    for launchers whose argparse runs after jax-importing imports. Also
+    places JAX's persistent compile cache: ``JAX_COMPILATION_CACHE_DIR``
+    stays as set, or becomes ``DEFAULT_CACHE_DIR`` when unset."""
     cfg = EnvConfig()
     if os.environ.get("REPRO_PLATFORM"):
         cfg.platform = os.environ["REPRO_PLATFORM"]
@@ -110,7 +123,12 @@ def apply_from_environ() -> Dict[str, str]:
         cfg.enable_x64 = os.environ["REPRO_X64"].lower() in _TRUTHY
     if os.environ.get("REPRO_HOST_DEVICES"):
         cfg.host_devices = int(os.environ["REPRO_HOST_DEVICES"])
-    return apply(cfg)
+    written = apply(cfg)
+    if not os.environ.get(CACHE_ENV_VAR):
+        os.environ[CACHE_ENV_VAR] = written[CACHE_ENV_VAR] = \
+            DEFAULT_CACHE_DIR
+    return written
 
 
-__all__ = ["EnvConfig", "GPU_XLA_FLAGS", "apply", "apply_from_environ"]
+__all__ = ["CACHE_ENV_VAR", "DEFAULT_CACHE_DIR", "EnvConfig", "GPU_XLA_FLAGS",
+           "apply", "apply_from_environ"]

@@ -2,24 +2,16 @@
 
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
-
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer jax; on older releases every mesh axis is implicitly
-Auto, so omitting the kwarg is equivalent.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:
-    from jax.sharding import AxisType
 
-    def _axis_kw(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # older jax: all axes are Auto by default
-    def _axis_kw(n_axes: int) -> dict:
-        return {}
+def _axis_kw(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

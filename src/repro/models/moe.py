@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..distributed.compat import shard_map
 from .common import ParamCollector, activation
 from .mlp import init_mlp, mlp_forward
 
@@ -145,7 +144,7 @@ def moe_forward(p, cfg: ArchConfig, x, mesh, model_axis: str = "model",
         aux = jax.lax.pmean(aux, all_axes)
         return y.reshape(bl, sl, el), aux[None]
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),

@@ -485,6 +485,27 @@ class KpcaEngine:
     def running(self) -> bool:
         return self._flusher is not None
 
+    def compiled_text(self, rows: Optional[int] = None) -> str:
+        """The compiled program (``as_text()``) that serves a ``rows``-row
+        slab (default ``cfg.max_batch``) of the live model: it shows which
+        kernel runs, e.g. a Mosaic ``tpu_custom_call`` on TPU when
+        ``cfg.use_pallas``. Single-device models only."""
+        if self._router is not None:
+            raise ValueError("compiled_text is for single-device models")
+        model = self.model
+        slab = np.zeros((rows or self.cfg.max_batch, model.n_features),
+                        np.float32)
+        if self.cfg.query_dtype is not None:
+            slab = slab.astype(self.cfg.query_dtype)
+        return self._proj.lower(model, slab).compile().as_text()
+
+    def placed_devices(self, policy: str) -> frozenset:
+        """Devices holding the sharded model's cached placement for
+        routing ``policy`` (empty before its first dispatch)."""
+        if self._router is None:
+            raise ValueError("placed_devices is for sharded models")
+        return self._router.placed_devices(policy)
+
     def __enter__(self) -> "KpcaEngine":
         return self.start()
 

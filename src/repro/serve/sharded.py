@@ -63,9 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..core.kernels_math import gram
+from ..core.kernels_math import HIGHEST, gram
 from ..core.oos import ShardedFittedKpca, finalize_partial_scores
-from ..distributed.compat import shard_map
 from ..launch.mesh import make_serving_mesh, mesh_shardings, replicate_on_mesh
 from ..obs import metrics, trace
 
@@ -83,7 +82,8 @@ def _shard_partial(spec, xq, xs, coefs_ext, gamma, use_pallas, interpret):
         from ..kernels.project import project_partial_op
         return project_partial_op(spec, xq, xs, coefs_ext, gamma=gamma,
                                   interpret=interpret)
-    return gram(spec, xq, xs, gamma=gamma) @ coefs_ext
+    return jnp.matmul(gram(spec, xq, xs, gamma=gamma), coefs_ext,
+                      precision=HIGHEST)
 
 
 def _pow2(n: int, floor: int = 8) -> int:
@@ -219,6 +219,16 @@ class ShardedRouter:
             self._placed[group] = (version, placed)
             self.n_placements += 1
         return placed
+
+    def placed_devices(self, policy: str) -> frozenset:
+        """Devices holding the cached placement ``policy`` dispatches to
+        (empty before its first dispatch, and for "single")."""
+        group = self._GROUPS[policy]
+        with self._lock:
+            hit = self._placed.get(group) if group is not None else None
+        if hit is None:
+            return frozenset()
+        return frozenset(hit[1].x_support.sharding.device_set)
 
     # -- jitted entry points ------------------------------------------------
 
@@ -390,7 +400,7 @@ def _partials_shard_map(model: ShardedFittedKpca, x_query: jax.Array, mesh,
                               interpret)
         return jax.lax.psum(part, axis_name)
 
-    f = shard_map(fn, mesh=mesh,
+    f = jax.shard_map(fn, mesh=mesh,
                   in_specs=(P(axis_name), P(axis_name), P(None, None), P()),
                   out_specs=P(None, None), check_vma=False)
     return f(model.x_support, model.coefs_ext, x_query, model.gamma)
@@ -414,7 +424,7 @@ def _scores_data_parallel(model: ShardedFittedKpca, x_query: jax.Array,
                                            use_pallas, interpret)
         return finalize_partial_scores(total, rmc, bias, n_support)
 
-    f = shard_map(fn, mesh=mesh,
+    f = jax.shard_map(fn, mesh=mesh,
                   in_specs=(P(), P(), P(axis_name, None), P(), P(), P()),
                   out_specs=P(axis_name, None), check_vma=False)
     return f(model.x_support, model.coefs_ext, x_query, model.gamma,

@@ -92,8 +92,7 @@ def check_compressed_psum():
         out, new_state = compressed_psum_grads({"w": g_loc}, st, mesh)
         return out["w"], new_state["w"]["p"], new_state["w"]["err"]
 
-    from repro.distributed.compat import shard_map
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None, None), P(None, None), P(None, None)),
         out_specs=(P(None, None), P(None, None), P(None, None)),

@@ -16,7 +16,9 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def _run(mode, marker):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    # JAX_PLATFORMS=cpu: the child must never contend for an accelerator
+    # the parent test process may hold.
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, HELPER, mode], env=env,
                          capture_output=True, text=True, timeout=900)

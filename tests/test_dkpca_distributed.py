@@ -14,7 +14,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run(mode):
-    env = dict(os.environ)
+    # JAX_PLATFORMS=cpu: the child must never contend for an accelerator
+    # the parent test process may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.abspath(SRC)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, HELPER, mode], env=env,

@@ -56,6 +56,16 @@ class TestEngineCorrectness:
             _rand((4, 12), seed=8))))
         np.testing.assert_allclose(r1.result(), want, rtol=1e-6, atol=1e-7)
 
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_compiled_text_is_the_serving_program(self, model, use_pallas):
+        eng = KpcaEngine(model, KpcaServeConfig(max_batch=16, min_bucket=4,
+                                                use_pallas=use_pallas))
+        text = eng.compiled_text()
+        assert "HloModule" in text and "f32[16,12]" in text
+        assert "f32[4,12]" in eng.compiled_text(4)
+        with pytest.raises(ValueError):
+            eng.placed_devices("mp")
+
     def test_interleaved_submit_flush(self, model):
         eng = KpcaEngine(model, KpcaServeConfig(max_batch=16, min_bucket=4))
         r1 = eng.submit(_rand((5, 12), seed=1))

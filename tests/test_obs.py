@@ -474,7 +474,8 @@ class TestRingCommAccounting:
         expected_setup = (2 * hops) * n * m * 4 + j * n * m * 4 \
             + (2 * hops) * n * 4
         assert led.setup.bytes == expected_setup
-        assert led.setup.collectives == 1               # centering pmean
+        # centering pmean + the default Theorem-2 rho2's scalar pmax
+        assert led.setup.collectives == 2
 
 
 # ---------------------------------------------------------------------------

@@ -284,6 +284,19 @@ class TestPlacementCache:
         router.dispatch(sharded, 1, q, "single")   # home placement: free
         assert router.n_placements == 3
 
+    @pytest.mark.parametrize("routing", ["mp", "dp"])
+    def test_engine_reports_placed_devices(self, fitted, routing):
+        sharded, _ = oos.shard_fitted(fitted, 4)
+        eng = KpcaEngine(sharded, KpcaServeConfig(max_batch=16, min_bucket=8,
+                                                  routing=routing))
+        assert eng.placed_devices(routing) == frozenset()
+        eng.project_many([_rand((16, M), seed=60)])
+        assert eng.placed_devices(routing) \
+            == frozenset(eng._router.mesh.devices.flat)
+        assert eng.placed_devices("single") == frozenset()
+        with pytest.raises(ValueError):
+            eng.compiled_text()
+
     def test_engine_drains_reuse_placement(self, fitted):
         sharded, _ = oos.shard_fitted(fitted, 4)
         eng = KpcaEngine(sharded, KpcaServeConfig(max_batch=16, min_bucket=8,

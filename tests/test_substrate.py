@@ -120,7 +120,9 @@ class TestCheckpoint:
                                               np.arange(64.0).reshape(8, 8))
             print("ELASTIC-OK")
         """)
-        env = dict(os.environ,
+        # JAX_PLATFORMS=cpu: the child must never contend for an
+        # accelerator the parent test process may hold.
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    PYTHONPATH=os.path.abspath(
                        os.path.join(os.path.dirname(__file__), "..", "src")))
         env.pop("XLA_FLAGS", None)
