@@ -199,7 +199,7 @@ class FaultTolerantRun:
                                  gamma=self.gamma)
         self.n_reknits += 1
         _M_REKNIT.inc()
-        if trace.is_enabled():
+        if trace.active() is not None:
             trace.complete("fault.recovery", time.perf_counter() - t0,
                            kind="dropout", t=t, dead=list(dead_ids),
                            survivors=len(surv_rows))

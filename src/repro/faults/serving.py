@@ -109,7 +109,7 @@ class ShardRebalancer:
             handle.publish(oos.drop_shard(model, exc.shard))
             self.n_rebalances += 1
             _M_REBALANCE.inc()
-            if trace.is_enabled():
+            if trace.active() is not None:
                 trace.complete("fault.recovery",
                                time.perf_counter() - t0,
                                kind="shard_loss", shard=exc.shard,

@@ -19,7 +19,8 @@ Design constraints, in order:
   1. **Zero-cost when disabled.** Tracing is off by default; ``span()``
      then returns one process-wide no-op context-manager singleton —
      no span object, no buffer append, no lock. The hot serving path
-     pays a function call and an identity ``with``.
+     pays a function call, one ``TraceAnnotation.is_enabled()`` check
+     once JAX is loaded, and an identity ``with``.
   2. **Bounded memory.** Events land in a fixed-capacity ring buffer
      (latest wins); a long-running server can trace forever and export
      the most recent window. ``n_dropped`` counts overwritten events.
@@ -37,11 +38,23 @@ For durations that do not nest on one thread (e.g. a request's
 queue-wait measured between the submitter thread and the flusher
 thread), ``complete(name, duration_s)`` records an already-finished
 span ending now; ``instant(name)`` records a point event.
+
+**Two outputs, one tracer.** While a JAX profiler capture is running
+(``jax.profiler.trace``/``start_trace`` or the profiler server), the
+module-level ``span()`` also opens a ``jax.profiler.TraceAnnotation`` of
+the same name and attributes on the calling thread, and ``instant()``
+records one entered and left at once, whether or not the ring buffer is
+enabled: the capture's ``.xplane.pb`` then holds the program's spans on
+the same clock as the device ops. ``complete()`` events are backdated,
+which the profiler cannot take, so they stay in the ring buffer only.
+This module never imports JAX: the annotation class is looked up once
+JAX is loaded.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -71,34 +84,48 @@ NOOP_SPAN = _NoopSpan()
 class Span:
     """One live span: records a complete ("X") event on ``__exit__`` —
     including on the exception path, so a raising body still closes its
-    span and the trace tree stays well-formed."""
+    span and the trace tree stays well-formed. ``tracer`` None records
+    into the profiler annotation ``ann`` alone."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 attrs: Dict[str, Any], ann=None):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self._t0 = 0
+        self._ann = ann
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end = time.perf_counter_ns()
-        self._tracer._record("X", self.name, self._t0, end - self._t0,
-                             self.attrs)
+        if self._tracer is not None:
+            self._tracer._record("X", self.name, self._t0, end - self._t0,
+                                 self.attrs)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def annotate(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (exported as ``args``)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_safe_attrs(attrs))
         return self
 
 
 def _json_safe(v):
     return v if isinstance(v, (str, int, float, bool, type(None))) else str(v)
+
+
+def _safe_attrs(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: _json_safe(v) for k, v in attrs.items()}
 
 
 class Tracer:
@@ -203,7 +230,7 @@ class Tracer:
             else:
                 ev["s"] = "t"
             if attrs:
-                ev["args"] = {k: _json_safe(v) for k, v in attrs.items()}
+                ev["args"] = _safe_attrs(attrs)
             out.append(ev)
         return {"traceEvents": out, "displayTimeUnit": "ms"}
 
@@ -219,6 +246,22 @@ class Tracer:
 # ---- process-wide tracer ---------------------------------------------------
 
 _tracer: Optional[Tracer] = None
+_annotation = None          # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _capture():
+    """``TraceAnnotation`` while a JAX profiler capture is running, else
+    None. Never imports JAX: until some other module has, there is no
+    capture to join."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        if ann is None:
+            return None
+        _annotation = ann
+    return ann if ann.is_enabled() else None
 
 
 def enable(capacity: int = _DEFAULT_CAPACITY) -> Tracer:
@@ -243,7 +286,10 @@ def install(tracer: Optional[Tracer]) -> None:
 
 
 def is_enabled() -> bool:
-    return _tracer is not None
+    """Whether ``span``/``instant`` record anywhere: the ring buffer is
+    enabled or a JAX profiler capture is running. (``active()`` says
+    whether the ring, which alone takes ``complete``, is on.)"""
+    return _tracer is not None or _capture() is not None
 
 
 def active() -> Optional[Tracer]:
@@ -252,21 +298,34 @@ def active() -> Optional[Tracer]:
 
 
 def span(name: str, **attrs):
-    """A ``with``-able span on the process-wide tracer — THE instrumentation
-    entry point. Returns the no-op singleton while tracing is disabled."""
+    """A ``with``-able span on the process-wide tracer and, while a
+    profiler capture runs, a ``TraceAnnotation`` — THE instrumentation
+    entry point. Returns the no-op singleton while neither is on."""
     t = _tracer
+    ann = _capture()
+    if ann is not None:
+        return Span(t, name, attrs, ann(name, **_safe_attrs(attrs)))
     if t is None:
         return NOOP_SPAN
     return Span(t, name, attrs)
 
 
 def instant(name: str, **attrs) -> None:
+    """A point event: in the ring buffer and, while a profiler capture
+    runs, an annotation entered and left at once (the profiler has no
+    point events; it lasts the annotation's own few microseconds)."""
     t = _tracer
     if t is not None:
         t.instant(name, **attrs)
+    ann = _capture()
+    if ann is not None:
+        with ann(name, **_safe_attrs(attrs)):
+            pass
 
 
 def complete(name: str, duration_s: float, **attrs) -> None:
+    """A backdated span (``Tracer.complete``): ring buffer only, since a
+    profiler annotation cannot start in the past."""
     t = _tracer
     if t is not None:
         t.complete(name, duration_s, **attrs)

@@ -40,6 +40,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import itertools
 import threading
 import time
 import warnings
@@ -246,22 +247,10 @@ class KpcaEngine:
             "serve_request_latency_seconds", "Per-request device wall time")
         self._m_wait = metrics.histogram(
             "serve_queue_wait_seconds", "Submit -> start-of-serve wait")
-        self._m_retries = metrics.counter(
-            "serve_retries_total", "Drain attempts retried after a fault")
-        self._m_expired = metrics.counter(
-            "serve_deadline_expired_total",
-            "Requests failed on the per-request deadline")
-        self._m_zero_copy = metrics.counter(
-            "serve_zero_copy_slabs_total",
-            "Slabs dispatched as arena slices (no pack copy)")
-        self._m_donated = metrics.counter(
-            "serve_donated_total", "Slabs dispatched with buffer donation")
-        self._m_arena_fallback = metrics.counter(
-            "serve_arena_fallback_total",
-            "Submits that missed the staging ring (malloc fallback)")
-        self._m_warmup = metrics.counter(
-            "serve_warmup_compiles_total",
-            "Programs compiled by the start() warmup pass")
+        # Drain numbers: every span of one drain carries the same
+        # ``drain`` attribute, across the flusher and device-runner
+        # threads (``next`` on a count is atomic under the GIL).
+        self._drain_ids = itertools.count(1)
 
         if isinstance(model, ShardedFittedKpca):
             from ..launch.mesh import make_serving_mesh
@@ -341,8 +330,6 @@ class KpcaEngine:
         # Stage the rows into the arena NOW so the flusher's pack is a
         # slice; a full ring falls back to the request's own array.
         arena_start = self._arena.stage(x) if x.shape[0] else None
-        if arena_start is None and x.shape[0]:
-            self._m_arena_fallback.inc()
         try:
             fut, shed = self._queue.put(x, n=x.shape[0],
                                         arena_start=arena_start)
@@ -374,14 +361,15 @@ class KpcaEngine:
         if not entries:
             return {}
         entries = list(entries)
+        drain = next(self._drain_ids)
         try:
-            out, served = self._serve_with_recovery(entries)
+            out, served = self._serve_with_recovery(entries, drain)
         except BaseException:
             # `entries` was pruned in place: expired futures are already
             # failed and must not re-enter the queue.
             self._queue.restore(entries)
             raise
-        self._resolve(served, out)
+        self._resolve(served, out, drain)
         return out
 
     def project_many(self, requests: Sequence[Any]) -> List[np.ndarray]:
@@ -453,10 +441,7 @@ class KpcaEngine:
                     xq = self._stage_slab(slab, warmup=True)
                     np.asarray(self._proj_donated(model, xq))
         with self._stats_lock:
-            built = self.stats.n_warmup_compiles - built0
-        if built:
-            self._m_warmup.inc(built)
-        return built
+            return self.stats.n_warmup_compiles - built0
 
     def close(self, drain: bool = True) -> None:
         """Stop the flusher thread (joined) and settle the queue: serve
@@ -531,55 +516,34 @@ class KpcaEngine:
                      and self._on_fault is None)
         inflight: collections.deque = collections.deque()
         last_n = 0                    # requests in the previous drain
+        # The number of the drain this loop is gathering: its wait and
+        # hold spans carry it, and it is taken when the drain is cut.
+        drain = next(self._drain_ids)
         try:
             while True:
-                has_work = self._queue.wait_for_work(
-                    trigger, self.cfg.flush_max_wait_s, self._stop)
+                with trace.span("serve.wait", drain=drain):
+                    has_work = self._queue.wait_for_work(
+                        trigger, self.cfg.flush_max_wait_s, self._stop)
                 if self._stop.is_set():
                     return            # close() settles whatever remains
                 if not has_work:
                     continue
                 while inflight and inflight[0].done():
                     inflight.popleft().result()
-                if inflight:
-                    # Dynamic batching: the device runner is busy, so
-                    # cutting a drain now buys nothing — the new slab
-                    # would only queue behind it. Hold the drain open
-                    # until the runner frees or a full batch forms;
-                    # every request arriving meanwhile rides one slab.
-                    while (not inflight[0].done() and not self._stop.is_set()
-                           and self._queue.depth < self.cfg.max_batch):
-                        time.sleep(5e-5)
-                    while inflight and inflight[0].done():
-                        inflight.popleft().result()
-                    if last_n > 1:
-                        # The drain that just finished resolved a wave;
-                        # give its submitters one stall window to
-                        # resubmit so the wave stays together instead of
-                        # splitting across two half-size drains.
-                        self._queue.coalesce(self.cfg.max_batch,
-                                             self.cfg.flush_coalesce_s,
-                                             self._stop)
-                elif last_n > 1:
-                    # Idle runner but the last drain resolved a WAVE of
-                    # submitters, who are all waking to resubmit right
-                    # now — yield until the wave lands so it drains as
-                    # one slab. A lone submitter (last_n <= 1) never
-                    # waits: there is no wave to collect, only latency
-                    # to add.
-                    self._queue.coalesce(self.cfg.max_batch,
-                                         self.cfg.flush_coalesce_s,
-                                         self._stop)
+                if inflight or last_n > 1:
+                    with trace.span("serve.hold", drain=drain):
+                        self._hold(inflight, last_n)
                 entries = self._queue.drain()
                 if not entries:
                     continue
                 entries = list(entries)
                 last_n = len(entries)
+                cut, drain = drain, next(self._drain_ids)
                 if pipelined:
                     while len(inflight) >= self.cfg.pipeline_depth:
                         inflight.popleft().result()
                     try:
-                        inflight.append(self._dispatch_async(entries))
+                        inflight.append(self._dispatch_async(entries, cut))
                     except BaseException as e:   # fail THIS batch only
                         self._fail_entries(entries, e)
                     with self._stats_lock:
@@ -587,16 +551,38 @@ class KpcaEngine:
                             self.stats.max_inflight_drains = len(inflight)
                     continue
                 try:
-                    out, served = self._serve_with_recovery(entries)
+                    out, served = self._serve_with_recovery(entries, cut)
                 except BaseException as e:   # fail THIS batch, keep serving
                     self._fail_entries(entries, e)
                     continue
-                self._resolve(served, out)
+                self._resolve(served, out, cut)
         finally:
             # Settle in-flight pipelined drains before the thread exits,
             # so close() observes every submitted future resolved.
             while inflight:
                 inflight.popleft().result()
+
+    def _hold(self, inflight: collections.deque, last_n: int) -> None:
+        """Hold a drain open before cutting it, while waiting is free."""
+        if inflight:
+            # Dynamic batching: the device runner is busy, so cutting a
+            # drain now buys nothing — the new slab would only queue
+            # behind it. Hold the drain open until the runner frees or a
+            # full batch forms; every request arriving meanwhile rides
+            # one slab.
+            while (not inflight[0].done() and not self._stop.is_set()
+                   and self._queue.depth < self.cfg.max_batch):
+                time.sleep(5e-5)
+            while inflight and inflight[0].done():
+                inflight.popleft().result()
+        if last_n > 1:
+            # The last drain resolved a WAVE of submitters, who are all
+            # waking to resubmit right now: give them one stall window so
+            # the wave drains as one slab instead of splitting across two
+            # half-size drains. A lone submitter (last_n <= 1) never
+            # waits: there is no wave to collect, only latency to add.
+            self._queue.coalesce(self.cfg.max_batch,
+                                 self.cfg.flush_coalesce_s, self._stop)
 
     def _fail_entries(self, entries, exc: BaseException) -> None:
         """Fail one drain's futures with ``exc`` (arena rows released)."""
@@ -606,12 +592,13 @@ class KpcaEngine:
                 en.future.set_exception(exc)
 
     @staticmethod
-    def _resolve(entries, out: dict) -> None:
+    def _resolve(entries, out: dict, drain: int) -> None:
         """Resolve one drain's futures. SlotFutures resolve through a
         shared per-flush slot table — one list publish + ONE event
         broadcast for the whole drain; anything else (decode-style
         RequestFutures) falls back to per-future set_result."""
-        with trace.span("serve.resolve", n_requests=len(entries)):
+        with trace.span("serve.resolve", drain=drain,
+                        n_requests=len(entries)):
             slot_pairs, results = [], []
             for e in entries:
                 if isinstance(e.future, SlotFuture):
@@ -649,12 +636,11 @@ class KpcaEngine:
             self._release_entries(expired)
             with self._stats_lock:
                 self.stats.n_deadline_expired += n_expired
-            self._m_expired.inc(n_expired)
             if trace.is_enabled():
                 trace.instant("serve.deadline_expired", n=n_expired)
         return live
 
-    def _serve_with_recovery(self, entries: list) -> tuple:
+    def _serve_with_recovery(self, entries: list, drain: int) -> tuple:
         """``_serve`` under the fault-tolerance contract: drop expired
         requests before every attempt, retry up to ``cfg.max_retries``
         times after a failure (invoking ``on_fault`` between attempts —
@@ -673,7 +659,7 @@ class KpcaEngine:
             if not live:
                 return {}, []
             try:
-                return self._serve(live), live
+                return self._serve(live, drain), live
             except BaseException as e:
                 if attempt >= self.cfg.max_retries:
                     raise
@@ -689,7 +675,6 @@ class KpcaEngine:
                         handled = False
                 with self._stats_lock:
                     self.stats.n_retries += 1
-                self._m_retries.inc()
                 if trace.is_enabled():
                     trace.instant("serve.retry", attempt=attempt,
                                   error=type(e).__name__, handled=handled)
@@ -698,7 +683,7 @@ class KpcaEngine:
                     self._stop.wait(
                         self.cfg.retry_backoff_s * (2 ** (attempt - 1)))
 
-    def _serve(self, entries) -> dict:
+    def _serve(self, entries, drain: int) -> dict:
         # One consistent (model, version) snapshot for the whole drain:
         # in-flight slabs finish on it even if a publish lands mid-drain.
         model, version = self.handle.get()
@@ -715,21 +700,24 @@ class KpcaEngine:
         #      jit call both happen in ``_run_slab`` on that thread);
         #   3. blocking gather (no lock), plan-based result assembly
         #      (pure slicing), then one stats commit.
-        with trace.span("serve.pack", n_requests=len(entries)):
+        with trace.span("serve.pack", drain=drain, n_requests=len(entries),
+                        rows=sum(e.n for e in entries)):
             slabs, plan, frames = pack_slabs(
                 entries, self.cfg.max_batch, self._buckets, self._arena)
         try:
             pool = self._device_pool
-            with trace.span("serve.dispatch", n_slabs=len(slabs)):
+            with trace.span("serve.dispatch", drain=drain,
+                            n_slabs=len(slabs)):
                 with self._dispatch_lock:
                     if pool is not None:
-                        launched = [pool.submit(self._run_slab, model,
-                                                version, slab)
+                        launched = [pool.submit(self._run_traced, drain,
+                                                model, version, slab)
                                     for slab, _, _ in slabs]
                     else:
-                        launched = [self._run_slab(model, version, slab)
+                        launched = [self._run_traced(drain, model, version,
+                                                     slab)
                                     for slab, _, _ in slabs]
-            with trace.span("serve.gather", n_slabs=len(slabs)):
+            with trace.span("serve.gather", drain=drain, n_slabs=len(slabs)):
                 done = [d.result() if pool is not None else d
                         for d in launched]
                 dts, host, padded, zero_copy, policies = \
@@ -740,9 +728,10 @@ class KpcaEngine:
             for f in frames:
                 self._arena.release_frame(f)
         return self._commit(entries, plan, dts, host, padded, zero_copy,
-                            policies, len(slabs), model, version, t_start)
+                            policies, len(slabs), model, version, t_start,
+                            drain)
 
-    def _dispatch_async(self, entries):
+    def _dispatch_async(self, entries, drain: int):
         """Pipelined drain (background flusher, fail-fast configs): pack
         and enqueue here, then hand the gather + assembly + future
         resolution to the device-runner thread as one more pool task —
@@ -753,19 +742,21 @@ class KpcaEngine:
         if self._inject_fault is not None:
             self._inject_fault(model)
         t_start = time.monotonic()
-        with trace.span("serve.pack", n_requests=len(entries)):
+        with trace.span("serve.pack", drain=drain, n_requests=len(entries),
+                        rows=sum(e.n for e in entries)):
             slabs, plan, frames = pack_slabs(
                 entries, self.cfg.max_batch, self._buckets, self._arena)
         pool = self._device_pool
-        with trace.span("serve.dispatch", n_slabs=len(slabs)):
+        with trace.span("serve.dispatch", drain=drain, n_slabs=len(slabs)):
             with self._dispatch_lock:
-                launched = [pool.submit(self._run_slab, model, version, slab)
+                launched = [pool.submit(self._run_traced, drain, model,
+                                        version, slab)
                             for slab, _, _ in slabs]
         return pool.submit(self._finalize, entries, slabs, plan, frames,
-                           launched, model, version, t_start)
+                           launched, model, version, t_start, drain)
 
     def _finalize(self, entries, slabs, plan, frames, launched, model,
-                  version, t_start) -> None:
+                  version, t_start, drain: int) -> None:
         """Device-runner half of a pipelined drain: gather (instant — the
         slab tasks ran before this one on the same serial pool), assemble,
         commit stats, resolve futures. Never raises: a failed slab fails
@@ -773,22 +764,25 @@ class KpcaEngine:
         contract."""
         try:
             try:
-                done = [d.result() for d in launched]
-                dts, host, padded, zero_copy, policies = \
-                    self._collect(slabs, done)
+                with trace.span("serve.gather", drain=drain,
+                                n_slabs=len(slabs)):
+                    done = [d.result() for d in launched]
+                    dts, host, padded, zero_copy, policies = \
+                        self._collect(slabs, done)
             finally:
                 for f in frames:
                     self._arena.release_frame(f)
-            out, touched = self._assemble(entries, plan, dts, host, model)
+            out, touched = self._assemble(entries, plan, dts, host, model,
+                                          drain)
             self._release_entries(entries)
         except BaseException as e:           # fail THIS batch only
             self._fail_entries(entries, e)
             return
         # Wake submitters FIRST: the stats/metrics tail runs in the shadow
         # of their next submit instead of on the request's critical path.
-        self._resolve(entries, out)
+        self._resolve(entries, out, drain)
         self._account(entries, dts, touched, padded, zero_copy, policies,
-                      len(slabs), version, t_start)
+                      len(slabs), version, t_start, drain)
 
     @staticmethod
     def _collect(slabs, done):
@@ -821,84 +815,86 @@ class KpcaEngine:
         return dts, host, padded, zero_copy, policies
 
     def _commit(self, entries, plan, dts, host, padded, zero_copy,
-                policies, n_slabs, model, version, t_start) -> dict:
+                policies, n_slabs, model, version, t_start, drain) -> dict:
         """Assembly + accounting tail for the synchronous drain (the
         pipelined finalize calls the two halves itself, with future
         resolution in between)."""
-        out, touched = self._assemble(entries, plan, dts, host, model)
+        out, touched = self._assemble(entries, plan, dts, host, model, drain)
         # Served: the staged rows are consumable again.
         self._release_entries(entries)
         self._account(entries, dts, touched, padded, zero_copy, policies,
-                      n_slabs, version, t_start)
+                      n_slabs, version, t_start, drain)
         return out
 
     @staticmethod
-    def _assemble(entries, plan, dts, host, model):
+    def _assemble(entries, plan, dts, host, model, drain: int):
         """Build per-request results straight off the pack plan: a request
         living in one slab gets a VIEW of that slab's scores, split
         requests copy each segment once. Returns (rid->scores,
         rid->device seconds touched)."""
-        empty = np.zeros((0, model.n_components), np.float32)
-        out, touched = {}, {}
-        for e, segs in zip(entries, plan):
-            if not segs:
-                out[e.rid] = empty
-                touched[e.rid] = 0.0
-                continue
-            if len(segs) == 1:
-                si, row, _off, m = segs[0]
-                out[e.rid] = host[si][row:row + m]
-            else:
-                buf = np.empty((e.n, host[segs[0][0]].shape[1]), np.float32)
-                for si, row, off, m in segs:
-                    buf[off:off + m] = host[si][row:row + m]
-                out[e.rid] = buf
-            touched[e.rid] = sum(dts[si] for si in {s[0] for s in segs})
-        return out, touched
+        with trace.span("serve.assemble", drain=drain):
+            empty = np.zeros((0, model.n_components), np.float32)
+            out, touched = {}, {}
+            for e, segs in zip(entries, plan):
+                if not segs:
+                    out[e.rid] = empty
+                    touched[e.rid] = 0.0
+                    continue
+                if len(segs) == 1:
+                    si, row, _off, m = segs[0]
+                    out[e.rid] = host[si][row:row + m]
+                else:
+                    buf = np.empty((e.n, host[segs[0][0]].shape[1]),
+                                   np.float32)
+                    for si, row, off, m in segs:
+                        buf[off:off + m] = host[si][row:row + m]
+                    out[e.rid] = buf
+                touched[e.rid] = sum(dts[si] for si in {s[0] for s in segs})
+            return out, touched
 
     def _account(self, entries, dts, touched, padded, zero_copy, policies,
-                 n_slabs, version, t_start) -> None:
+                 n_slabs, version, t_start, drain: int) -> None:
         """Stats + metric publication for one served drain. Runs only
         after every slab resolved, so a failed-then-retried flush doesn't
         double-count its slabs."""
-        waits = [max(0.0, t_start - e.t_submit) for e in entries]
-        donated = n_slabs if self.cfg.donate else 0
-        routed = collections.Counter(p for p in policies if p)
-        with self._stats_lock:
-            self.stats.n_routed_mp += routed.get("mp", 0)
-            self.stats.n_routed_dp += routed.get("dp", 0)
-            self.stats.n_routed_single += routed.get("single", 0)
-            self.stats.n_padded += padded
-            self.stats.total_time_s += sum(dts)
-            self.stats.n_requests += len(entries)
-            self.stats.n_queries += sum(e.n for e in entries)
-            self.stats.n_flushes += 1
-            self.stats.n_zero_copy_slabs += zero_copy
-            self.stats.n_donated += donated
-            self.stats.n_arena_fallback = self._arena.n_fallback
-            for e, wait in zip(entries, waits):
-                self.stats.per_request.append(RequestStats(
-                    e.rid, e.n, touched[e.rid], version, queue_wait_s=wait))
-        # Metric publication rides the same per-drain commit point (one
-        # batch of updates per drain, nothing on the submit hot path).
-        self._m_requests.inc(len(entries))
-        self._m_queries.inc(sum(e.n for e in entries))
-        self._m_padded.inc(padded)
-        self._m_flushes.inc()
-        self._m_depth.set(self._queue.depth)
-        self._m_version.set(version)
-        if zero_copy:
-            self._m_zero_copy.inc(zero_copy)
-        if donated:
-            self._m_donated.inc(donated)
-        self._m_latency.observe_many(list(touched.values()))
-        self._m_wait.observe_many(waits)
-        if trace.is_enabled():
-            for e, wait in zip(entries, waits):
-                # Backdated complete event: the submit->serve gap renders
-                # as its own "queue_wait" phase without any submit-side
-                # instrumentation.
-                trace.complete("serve.queue_wait", wait, rid=e.rid, n=e.n)
+        with trace.span("serve.account", drain=drain):
+            waits = [max(0.0, t_start - e.t_submit) for e in entries]
+            donated = n_slabs if self.cfg.donate else 0
+            routed = collections.Counter(p for p in policies if p)
+            with self._stats_lock:
+                self.stats.n_routed_mp += routed.get("mp", 0)
+                self.stats.n_routed_dp += routed.get("dp", 0)
+                self.stats.n_routed_single += routed.get("single", 0)
+                self.stats.n_padded += padded
+                self.stats.total_time_s += sum(dts)
+                self.stats.n_requests += len(entries)
+                self.stats.n_queries += sum(e.n for e in entries)
+                self.stats.n_flushes += 1
+                self.stats.n_zero_copy_slabs += zero_copy
+                self.stats.n_donated += donated
+                self.stats.n_arena_fallback = self._arena.n_fallback
+                for e, wait in zip(entries, waits):
+                    self.stats.per_request.append(RequestStats(
+                        e.rid, e.n, touched[e.rid], version,
+                        queue_wait_s=wait))
+            # Metric publication rides the same per-drain commit point
+            # (one batch of updates per drain, nothing on the submit hot
+            # path).
+            self._m_requests.inc(len(entries))
+            self._m_queries.inc(sum(e.n for e in entries))
+            self._m_padded.inc(padded)
+            self._m_flushes.inc()
+            self._m_depth.set(self._queue.depth)
+            self._m_version.set(version)
+            self._m_latency.observe_many(list(touched.values()))
+            self._m_wait.observe_many(waits)
+            if trace.active() is not None:
+                for e, wait in zip(entries, waits):
+                    # Backdated complete event (ring buffer only): the
+                    # submit->serve gap renders as its own "queue_wait"
+                    # phase without any submit-side instrumentation.
+                    trace.complete("serve.queue_wait", wait, rid=e.rid,
+                                   n=e.n)
 
     def _stage_slab(self, slab: np.ndarray, warmup: bool = False,
                     policy: Optional[str] = None) -> np.ndarray:
@@ -929,6 +925,16 @@ class KpcaEngine:
                     self.stats.n_compiles += 1
         return xq
 
+    def _run_traced(self, drain: int, model, version, slab):
+        """``_run_slab`` in the drain's ``serve.device`` span: staging and
+        the jit call (the host->device copy and the enqueue), not the
+        device's compute, which ends in the gather. Kept apart so that
+        ``_run_slab(model, version, slab)`` stays the seam fault tests
+        replace."""
+        with trace.span("serve.device", drain=drain,
+                        rows=int(slab.shape[0])):
+            return self._run_slab(model, version, slab)
+
     def _run_slab(self, model, version, slab):
         """Stage + dispatch one packed slab on the CALLING thread (the
         device-runner when ``start()`` is up, so the ~flat per-transfer
@@ -939,14 +945,13 @@ class KpcaEngine:
         itself; the on-device copy it makes is dead after the call when
         donation is on, and the caller owns the device->host get."""
         t0 = time.perf_counter()
-        with trace.span("serve.device", rows=int(slab.shape[0])):
-            if self._router is not None:
-                policy = self._router.choose(int(slab.shape[0]), model)
-                xq = self._stage_slab(slab, policy=policy)
-                out = self._router.dispatch(model, version, xq, policy)
-            else:
-                xq = self._stage_slab(slab)
-                out = self._proj_donated(model, xq)
+        if self._router is not None:
+            policy = self._router.choose(int(slab.shape[0]), model)
+            xq = self._stage_slab(slab, policy=policy)
+            out = self._router.dispatch(model, version, xq, policy)
+        else:
+            xq = self._stage_slab(slab)
+            out = self._proj_donated(model, xq)
         return out, time.perf_counter() - t0
 
 

@@ -225,6 +225,89 @@ class TestEnabledTracerBudget:
         assert [e[1] for e in outer.events()] == ["before"]
 
 
+def _capture(tmp_path, body):
+    """Run ``body`` under a JAX profiler capture; returns the trace's
+    events as ``(thread line, name, duration_ns, stats)``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(f"{p.name}#{i}", e.name, e.duration_ns, dict(e.stats))
+            for p in ProfileData.from_file(path).planes
+            for i, line in enumerate(p.lines) for e in line.events]
+
+
+class TestProfilerBridge:
+    def test_span_reaches_the_capture_on_the_calling_thread(self, tmp_path):
+        trace.disable()
+
+        def body():
+            def work():
+                with jax.profiler.TraceAnnotation("marker"):
+                    pass
+                with trace.span("x", a=1):
+                    pass
+            th = threading.Thread(target=work)
+            th.start()
+            th.join()
+
+        events = _capture(tmp_path, body)
+        (x,) = [e for e in events if e[1] == "x"]
+        (marker,) = [e for e in events if e[1] == "marker"]
+        assert x[3] == {"a": 1}
+        assert x[0] == marker[0]             # the worker thread's line
+        assert trace.active() is None        # the ring stayed off
+
+    def test_no_capture_opens_no_annotation(self, monkeypatch):
+        trace.disable()
+        opened = []
+
+        class Spy:
+            def __init__(self, *a, **k):
+                opened.append(a)
+
+            @staticmethod
+            def is_enabled():
+                return False
+
+        monkeypatch.setattr(trace, "_annotation", Spy)
+        assert trace.span("x", a=1) is NOOP_SPAN  # repro-lint: disable=span-not-closed
+        trace.instant("y")
+        assert not trace.is_enabled() and opened == []
+
+    def test_instant_and_complete_under_a_capture(self, tmp_path):
+        t = trace.enable()
+
+        def body():
+            trace.instant("point", n=3)
+            trace.complete("backdated", 0.5, rid=1)
+            with trace.span("both", shape=[2, 3]):   # a list attribute
+                pass
+
+        events = _capture(tmp_path, body)
+        names = {e[1]: e for e in events}
+        # entered and left at once: the annotation's own cost, microseconds
+        assert names["point"][2] < 1e6 and names["point"][3] == {"n": 3}
+        assert "backdated" not in names      # ring only
+        assert names["both"][3] == {"shape": "[2, 3]"}
+        assert [e[1] for e in t.events()] == ["point", "backdated", "both"]
+
+    def test_obs_imports_no_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys, repro.obs; from repro.obs import trace; "
+                "s = trace.span('x'); trace.instant('y'); "
+                "assert s is trace.NOOP_SPAN and 'jax' not in sys.modules")
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
 class TestChromeExport:
     def test_round_trip_schema(self, tmp_path):
         t = Tracer()
