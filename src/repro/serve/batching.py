@@ -81,6 +81,9 @@ class EngineStats:
     n_routed_dp: int = 0          # sharded slabs routed data-parallel
     n_routed_single: int = 0      # sharded slabs routed single-device
     max_inflight_drains: int = 0  # peak pipelined drains in flight at once
+    n_inline_drains: int = 0      # drains cut and finished on the flusher
+    #                               thread (no device runner: jit dispatch
+    #                               is asynchronous on the model's devices)
     total_time_s: float = 0.0
     # Ring of the most recent PER_REQUEST_WINDOW requests (bounded: a
     # long-running async engine must not accumulate one record per request

@@ -40,6 +40,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -67,6 +68,29 @@ from .sharded import ShardedRouter, ShardedScores
 # surface on every warmup — keep the filter as narrow as the message.
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
+
+
+def drains_inline(platform: str) -> bool:
+    """Whether the background flusher runs each whole drain itself on a
+    model held by devices of ``platform``, with no device-runner thread.
+
+    On CPU a jit call blocks on compute inline, so a device-runner thread
+    lets the flusher pack the next drain while this one computes. On TPU
+    and GPU the call returns before the program runs: the runner overlaps
+    nothing there, and only adds a thread hop and a polled hold to every
+    drain."""
+    return platform != "cpu"
+
+
+def _platform(model, mesh) -> str:
+    """Platform of the devices holding ``model`` (the mesh's when given);
+    a model on the host goes where jit puts it, the default backend."""
+    if mesh is not None:
+        return mesh.devices.flat[0].platform
+    devices = getattr(model.x_support, "devices", None)
+    if devices is None:
+        return jax.default_backend()
+    return next(iter(devices())).platform
 
 
 @dataclasses.dataclass
@@ -218,10 +242,10 @@ class KpcaEngine:
                                    on_shed=self._release_entries)
         self._stop = threading.Event()
         self._flusher: Optional[threading.Thread] = None
-        # Device-runner thread (created by start()): on backends where jit
-        # calls block on compute inline (CPU), it keeps the flusher's
-        # dispatch phase enqueue-only so packing the next drain overlaps
-        # the device work of this one.
+        # Device-runner thread (created by start() only where jit calls
+        # block on compute inline, CPU: see ``drains_inline``): it keeps
+        # the flusher's dispatch phase enqueue-only so packing the next
+        # drain overlaps the device work of this one.
         self._device_pool: Optional[concurrent.futures.ThreadPoolExecutor] \
             = None
         # Cached metric handles, resolved once: the hot path must not pay
@@ -393,16 +417,19 @@ class KpcaEngine:
         failed drain fails exactly the futures of that batch (no retry
         loop) and keeps serving.
 
-        Also brings up the rest of the steady-state hot path: the
-        device-runner thread (dispatch becomes enqueue-only) and — unless
-        ``cfg.warmup`` is off — a warmup pass compiling every pow2
-        bucket's program so traffic never sees a compile
-        (``stats.n_compiles`` stays 0; warmup builds are counted in
-        ``stats.n_warmup_compiles``).
+        Also brings up the rest of the steady-state hot path: where the
+        model's devices run jit calls inline (CPU; ``drains_inline``), the
+        device-runner thread (dispatch becomes enqueue-only) — elsewhere
+        the flusher runs each drain itself — and, unless ``cfg.warmup`` is
+        off, a warmup pass compiling every pow2 bucket's program so
+        traffic never sees a compile (``stats.n_compiles`` stays 0;
+        warmup builds are counted in ``stats.n_warmup_compiles``).
         """
         if self._flusher is not None:
             return self
-        if self._device_pool is None:
+        mesh = self._router.mesh if self._router is not None else None
+        if (self._device_pool is None
+                and not drains_inline(_platform(self.model, mesh))):
             self._device_pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="kpca-device")
         if self.cfg.warmup:
@@ -507,13 +534,16 @@ class KpcaEngine:
             else (self.cfg.flush_min_queries or self.cfg.max_batch)
         # Pipelined drains hand the device wait + result assembly + future
         # resolution to the device-runner thread, so submitter wakeups and
-        # the NEXT drain's pack overlap this drain's compute. Retries,
-        # deadlines, and recovery hooks need the synchronous drain (they
-        # re-attempt with restored state), so those configs keep it.
-        pipelined = (self._device_pool is not None
-                     and self.cfg.max_retries == 0
+        # the NEXT drain's pack overlap this drain's compute. Without a
+        # runner (asynchronous jit dispatch) the flusher runs each drain
+        # whole, then cuts the next. Retries, deadlines, and recovery
+        # hooks need the synchronous drain (they re-attempt with restored
+        # state), so those configs keep it.
+        inline = self._device_pool is None    # drains end on this thread
+        fail_fast = (self.cfg.max_retries == 0
                      and self.cfg.request_deadline_s is None
                      and self._on_fault is None)
+        pipelined = fail_fast and not inline
         inflight: collections.deque = collections.deque()
         last_n = 0                    # requests in the previous drain
         # The number of the drain this loop is gathering: its wait and
@@ -539,6 +569,9 @@ class KpcaEngine:
                 entries = list(entries)
                 last_n = len(entries)
                 cut, drain = drain, next(self._drain_ids)
+                if fail_fast and inline:
+                    self._drain_inline(entries, cut)
+                    continue
                 if pipelined:
                     while len(inflight) >= self.cfg.pipeline_depth:
                         inflight.popleft().result()
@@ -551,7 +584,8 @@ class KpcaEngine:
                             self.stats.max_inflight_drains = len(inflight)
                     continue
                 try:
-                    out, served = self._serve_with_recovery(entries, cut)
+                    out, served = self._serve_with_recovery(
+                        entries, cut, inline=inline)
                 except BaseException as e:   # fail THIS batch, keep serving
                     self._fail_entries(entries, e)
                     continue
@@ -563,7 +597,9 @@ class KpcaEngine:
                 inflight.popleft().result()
 
     def _hold(self, inflight: collections.deque, last_n: int) -> None:
-        """Hold a drain open before cutting it, while waiting is free."""
+        """Hold a drain open before cutting it, while waiting is free.
+        With no device runner (inline drains) nothing is ever in flight,
+        so only the wave coalesce applies."""
         if inflight:
             # Dynamic batching: the device runner is busy, so cutting a
             # drain now buys nothing — the new slab would only queue
@@ -640,7 +676,8 @@ class KpcaEngine:
                 trace.instant("serve.deadline_expired", n=n_expired)
         return live
 
-    def _serve_with_recovery(self, entries: list, drain: int) -> tuple:
+    def _serve_with_recovery(self, entries: list, drain: int,
+                             inline: bool = False) -> tuple:
         """``_serve`` under the fault-tolerance contract: drop expired
         requests before every attempt, retry up to ``cfg.max_retries``
         times after a failure (invoking ``on_fault`` between attempts —
@@ -650,7 +687,8 @@ class KpcaEngine:
         Prunes ``entries`` IN PLACE to the still-live subset (callers
         use it for restore-on-error) and returns ``(out, served)``.
         With ``max_retries=0`` and no deadline this is exactly one
-        ``_serve`` call — the pre-fault-layer behavior.
+        ``_serve`` call — the pre-fault-layer behavior. ``inline`` counts
+        the drain in ``stats.n_inline_drains``.
         """
         attempt = 0
         while True:
@@ -659,7 +697,7 @@ class KpcaEngine:
             if not live:
                 return {}, []
             try:
-                return self._serve(live, drain), live
+                return self._serve(live, drain, inline), live
             except BaseException as e:
                 if attempt >= self.cfg.max_retries:
                     raise
@@ -683,7 +721,32 @@ class KpcaEngine:
                     self._stop.wait(
                         self.cfg.retry_backoff_s * (2 ** (attempt - 1)))
 
-    def _serve(self, entries, drain: int) -> dict:
+    def _serve(self, entries, drain: int, inline: bool = False) -> dict:
+        """One drain, accounted before the caller resolves it; returns
+        {request id: scores}."""
+        out, account = self._drain(entries, drain)
+        account(inline=inline)
+        return out
+
+    def _drain_inline(self, entries, drain: int) -> None:
+        """A whole fail-fast drain on the flusher thread, where jit
+        dispatch is asynchronous (no device runner): pack, one jit call
+        per slab, blocking gets, assembly, then the futures, then the
+        accounting in the shadow of their next submit. Never raises: a
+        failure fails exactly this drain's futures."""
+        try:
+            out, account = self._drain(entries, drain)
+        except BaseException as e:
+            self._fail_entries(entries, e)
+            return
+        self._resolve(entries, out, drain)
+        account(inline=True)
+
+    def _drain(self, entries, drain: int) -> tuple:
+        """Pack, dispatch, gather and assemble one drain (the slabs on the
+        device runner when it is up). Returns ``(out, account)``: the
+        results by request id, and the drain's ``_account`` call, left to
+        the caller so it picks the order of accounting and resolution."""
         # One consistent (model, version) snapshot for the whole drain:
         # in-flight slabs finish on it even if a publish lands mid-drain.
         model, version = self.handle.get()
@@ -694,10 +757,12 @@ class KpcaEngine:
         # Three-phase drain so no device sync ever happens under a lock:
         #   1. plan-pack (arena slices, not gather-concat) — pure slicing;
         #   2. dispatch every slab under _dispatch_lock — enqueue-only:
-        #      with the device-runner thread up (start()), the critical
-        #      section is a handful of executor submits even on backends
-        #      where a jit call blocks on compute inline (staging and the
-        #      jit call both happen in ``_run_slab`` on that thread);
+        #      with the device-runner thread up (start() on CPU), the
+        #      critical section is a handful of executor submits even
+        #      though a jit call blocks on compute inline there (staging
+        #      and the jit call both happen in ``_run_slab`` on that
+        #      thread); elsewhere the jit calls themselves return before
+        #      the programs run;
         #   3. blocking gather (no lock), plan-based result assembly
         #      (pure slicing), then one stats commit.
         with trace.span("serve.pack", drain=drain, n_requests=len(entries),
@@ -727,9 +792,12 @@ class KpcaEngine:
             # staged device copies already happened, nothing reads them.
             for f in frames:
                 self._arena.release_frame(f)
-        return self._commit(entries, plan, dts, host, padded, zero_copy,
-                            policies, len(slabs), model, version, t_start,
-                            drain)
+        out, touched = self._assemble(entries, plan, dts, host, model, drain)
+        # Served: the staged rows are consumable again.
+        self._release_entries(entries)
+        return out, functools.partial(
+            self._account, entries, dts, touched, padded, zero_copy,
+            policies, len(slabs), version, t_start, drain)
 
     def _dispatch_async(self, entries, drain: int):
         """Pipelined drain (background flusher, fail-fast configs): pack
@@ -814,18 +882,6 @@ class KpcaEngine:
             zero_copy += bool(zc)
         return dts, host, padded, zero_copy, policies
 
-    def _commit(self, entries, plan, dts, host, padded, zero_copy,
-                policies, n_slabs, model, version, t_start, drain) -> dict:
-        """Assembly + accounting tail for the synchronous drain (the
-        pipelined finalize calls the two halves itself, with future
-        resolution in between)."""
-        out, touched = self._assemble(entries, plan, dts, host, model, drain)
-        # Served: the staged rows are consumable again.
-        self._release_entries(entries)
-        self._account(entries, dts, touched, padded, zero_copy, policies,
-                      n_slabs, version, t_start, drain)
-        return out
-
     @staticmethod
     def _assemble(entries, plan, dts, host, model, drain: int):
         """Build per-request results straight off the pack plan: a request
@@ -853,10 +909,12 @@ class KpcaEngine:
             return out, touched
 
     def _account(self, entries, dts, touched, padded, zero_copy, policies,
-                 n_slabs, version, t_start, drain: int) -> None:
-        """Stats + metric publication for one served drain. Runs only
-        after every slab resolved, so a failed-then-retried flush doesn't
-        double-count its slabs."""
+                 n_slabs, version, t_start, drain: int,
+                 inline: bool = False) -> None:
+        """Stats + metric publication for one served drain (``inline``:
+        cut and finished on the flusher thread). Runs only after every
+        slab resolved, so a failed-then-retried flush doesn't double-count
+        its slabs."""
         with trace.span("serve.account", drain=drain):
             waits = [max(0.0, t_start - e.t_submit) for e in entries]
             donated = n_slabs if self.cfg.donate else 0
@@ -870,6 +928,7 @@ class KpcaEngine:
                 self.stats.n_requests += len(entries)
                 self.stats.n_queries += sum(e.n for e in entries)
                 self.stats.n_flushes += 1
+                self.stats.n_inline_drains += inline
                 self.stats.n_zero_copy_slabs += zero_copy
                 self.stats.n_donated += donated
                 self.stats.n_arena_fallback = self._arena.n_fallback
@@ -937,8 +996,8 @@ class KpcaEngine:
 
     def _run_slab(self, model, version, slab):
         """Stage + dispatch one packed slab on the CALLING thread (the
-        device-runner when ``start()`` is up, so the ~flat per-transfer
-        cost overlaps the flusher's next pack). Returns
+        device-runner where ``start()`` brought one up, so the ~flat
+        per-transfer cost overlaps the flusher's next pack). Returns
         ``(device scores, seconds)``; for sharded models the scores carry
         the routing policy (``ShardedScores``) and the version keys the
         router's placement cache. Dispatch transfers the host slab

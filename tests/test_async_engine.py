@@ -7,6 +7,7 @@ closes the flusher; publishers are context-managed), so a deadlock shows
 up as a pytest-timeout failure, not a hung CI job.
 """
 
+import collections
 import threading
 
 import jax
@@ -15,8 +16,9 @@ import numpy as np
 import pytest
 
 from repro.core import KernelSpec, oos
+from repro.obs import trace
 from repro.serve import (KpcaEngine, KpcaServeConfig, ModelHandle,
-                         QueueFullError, ShedError)
+                         QueueFullError, ShedError, kpca_engine)
 from repro.serve.sharded import project_sharded
 
 SPEC = KernelSpec(kind="rbf", gamma=0.25)
@@ -257,3 +259,191 @@ class TestVersionConsistencyUnderRefresh:
             want = np.asarray(ref(versions[v], jnp.asarray(xq)))
             np.testing.assert_array_equal(got, want)
         assert seen                            # every request attributed
+
+def _runner_threads():
+    return {t for t in threading.enumerate()
+            if t.name.startswith("kpca-device")}
+
+
+class TestDrainPlacement:
+    """Which thread finishes a drain follows the platform of the model's
+    devices: a device-runner thread on CPU (jit calls block on compute),
+    the flusher itself where jit dispatch is asynchronous."""
+
+    @pytest.mark.parametrize("platform,inline", [
+        ("cpu", False), ("tpu", True), ("gpu", True)])
+    def test_decision_by_platform(self, platform, inline):
+        assert kpca_engine.drains_inline(platform) is inline
+
+    def test_model_platform_is_read_off_its_devices(self, model):
+        assert kpca_engine._platform(model, None) == "cpu"
+        sharded, _ = oos.shard_fitted(model, 2)
+        eng = KpcaEngine(sharded, KpcaServeConfig(max_batch=8, min_bucket=8))
+        assert kpca_engine._platform(sharded, eng._router.mesh) == "cpu"
+
+    def test_cpu_default_keeps_the_device_runner(self, model, engine):
+        eng = engine(KpcaServeConfig(max_batch=16, min_bucket=8,
+                                     flush_max_wait_s=0.002))
+        before = _runner_threads()
+        eng.start()
+        futs = [eng.submit(_rand((q, 12), seed=300 + q)) for q in (1, 9)]
+        for f in futs:
+            f.result(timeout=WAIT)
+        assert _runner_threads() - before     # started by the first slab
+        eng.close()
+        assert eng.stats.n_flushes > 0
+        assert eng.stats.n_inline_drains == 0
+
+
+@pytest.fixture
+def inline_drains(monkeypatch):
+    """Select the flusher-only drain path on the CPU, as on TPU."""
+    monkeypatch.setattr(kpca_engine, "drains_inline", lambda platform: True)
+
+
+@pytest.mark.usefixtures("inline_drains")
+class TestInlineDrains:
+    def test_results_match_project_per_request(self, model, engine):
+        eng = engine(KpcaServeConfig(max_batch=128, min_bucket=8,
+                                     flush_max_wait_s=0.002))
+        before = _runner_threads()
+        eng.start()
+        assert eng._device_pool is None
+        sizes = [1, 7, 128, 300, 7, 1, 300, 128, 1]
+        reqs = [_rand((q, 12), seed=400 + i) for i, q in enumerate(sizes)]
+        futs = [eng.submit(r) for r in reqs]
+        got = [f.result(timeout=WAIT) for f in futs]
+        assert not _runner_threads() - before
+        eng.close()
+        for r, g in zip(reqs, got):
+            want = np.asarray(oos.project(model, jnp.asarray(r)))
+            np.testing.assert_allclose(g, want, rtol=1e-6, atol=1e-7)
+        assert eng.stats.n_inline_drains == eng.stats.n_flushes > 0
+        assert eng.stats.n_requests == len(sizes)
+        assert eng.stats.max_inflight_drains == 0
+
+    def test_sharded_model_drains_inline(self, model):
+        sharded, _ = oos.shard_fitted(model, 2)
+        eng = KpcaEngine(sharded, KpcaServeConfig(
+            max_batch=16, min_bucket=8, routing="mp",
+            flush_max_wait_s=0.002))
+        try:
+            eng.start()
+            reqs = [_rand((q, 12), seed=500 + q) for q in (3, 16, 21)]
+            futs = [eng.submit(r) for r in reqs]
+            got = [f.result(timeout=WAIT) for f in futs]
+        finally:
+            eng.close(drain=False)
+        for r, g in zip(reqs, got):
+            want = np.asarray(oos.project(model, jnp.asarray(r)))
+            np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-6)
+        assert eng.stats.n_inline_drains == eng.stats.n_flushes > 0
+        assert eng.stats.n_routed_mp > 0
+
+    def test_resolve_ends_before_account_starts(self, model, engine):
+        eng = engine(KpcaServeConfig(max_batch=16, min_bucket=8,
+                                     flush_max_wait_s=0.002))
+        tracer = trace.Tracer()
+        outer = trace.active()
+        trace.install(tracer)
+        try:
+            eng.start()
+            for w in range(6):
+                futs = [eng.submit(_rand((1 + (w + i) % 9, 12), seed=w))
+                        for i in range(4)]
+                for f in futs:
+                    f.result(timeout=WAIT)
+            eng.close()
+        finally:
+            trace.install(outer)
+        spans = collections.defaultdict(dict)
+        threads = collections.defaultdict(set)
+        for ph, name, t0, dur, tid, attrs in tracer.events():
+            if ph == "X" and "drain" in attrs:
+                spans[attrs["drain"]][name] = (t0, t0 + dur)
+                threads[attrs["drain"]].add(tid)
+        served = [d for d, s in spans.items() if "serve.account" in s]
+        assert len(served) == eng.stats.n_flushes > 1
+        for d in served:
+            assert spans[d]["serve.resolve"][1] <= \
+                spans[d]["serve.account"][0]
+            assert len(threads[d]) == 1       # the flusher's, start to end
+
+    @pytest.mark.parametrize("drain", [True, False])
+    def test_close_settles_every_future(self, model, engine, drain):
+        eng = engine(KpcaServeConfig(max_batch=16, min_bucket=8,
+                                     flush_max_wait_s=0.002)).start()
+        futs = [eng.submit(_rand((1 + i % 20, 12), seed=600 + i))
+                for i in range(60)]
+        eng.close(drain=drain)
+        assert all(f.done() for f in futs)
+        for f in futs:
+            if drain or not f.cancelled():
+                assert f.result(timeout=0).shape[1] == 2
+        if drain:
+            assert not any(f.cancelled() for f in futs)
+
+    def test_retrying_engine_serves_and_retries(self, model, engine):
+        eng = engine(KpcaServeConfig(max_batch=8, min_bucket=8,
+                                     flush_max_wait_s=0.002, max_retries=1,
+                                     retry_backoff_s=0.001))
+        run_slab = eng._run_slab
+        boom = dict(armed=True)
+
+        def fail_once(mdl, version, slab):
+            if boom["armed"]:
+                boom["armed"] = False
+                raise RuntimeError("injected")
+            return run_slab(mdl, version, slab)
+
+        eng._run_slab = fail_once
+        eng.start()
+        assert eng._device_pool is None
+        x = _rand((5, 12), seed=700)
+        got = eng.submit(x).result(timeout=WAIT)
+        eng.close()
+        np.testing.assert_allclose(
+            got, np.asarray(oos.project(model, jnp.asarray(x))),
+            rtol=1e-6, atol=1e-7)
+        assert eng.stats.n_retries == 1
+        assert eng.stats.n_inline_drains == eng.stats.n_flushes == 1
+
+    def test_drain_spans_sit_on_one_thread_line(self, engine, tmp_path):
+        """Under a profiler capture every span of a drain, from its wait
+        to its account, is on the flusher's line (the inline twin of
+        tests/bench/test_bench_drains.py's cross-thread check)."""
+        from jax.profiler import ProfileData
+        eng = engine(KpcaServeConfig(max_batch=16, min_bucket=8)).start()
+        rng = np.random.default_rng(0)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for w in range(8):
+                futs = [eng.submit(rng.normal(size=(1 + (w * 5 + i) % 20, 12))
+                                   .astype(np.float32)) for i in range(5)]
+                for f in futs:
+                    f.result(timeout=WAIT)
+            eng.close()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        lines = collections.defaultdict(set)
+        names = collections.defaultdict(list)
+        for plane in ProfileData.from_file(str(path)).planes:
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    d = dict(e.stats).get("drain")
+                    if e.name.startswith("serve.") and d is not None:
+                        lines[int(d)].add(f"{plane.name}#{i}")
+                        names[int(d)].append(e.name)
+        served = [d for d, n in names.items() if "serve.account" in n]
+        assert len(served) == eng.stats.n_flushes > 1
+        assert eng.stats.n_inline_drains == eng.stats.n_flushes
+        for d in served:
+            for name in ("serve.pack", "serve.dispatch", "serve.gather",
+                         "serve.assemble", "serve.resolve", "serve.account"):
+                assert names[d].count(name) == 1, (name, names[d])
+            assert names[d].count("serve.device") >= 1
+            assert len(lines[d]) == 1, lines[d]
+        assert any("serve.wait" in n for n in names.values())
