@@ -32,12 +32,14 @@ sets, which ``landmark_schedule``'s fixed-seed prefixes guarantee.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace
 from .kernels_math import HIGHEST, KernelSpec, gram, resolve_gamma
 
 # Shape conventions used throughout this module:
@@ -104,6 +106,46 @@ def _as_2d(alpha: jax.Array) -> jax.Array:
     return alpha[:, None] if alpha.ndim == 1 else alpha
 
 
+# Gram elements per row block of the kernel-mean pass (2**26 float32, 256
+# MiB): a support of L rows is read in blocks of KERNEL_MEAN_BLOCK_ELEMS // L
+# rows, so the pass holds one (rows, L) tile and never the (L, L) Gram.
+KERNEL_MEAN_BLOCK_ELEMS = 2 ** 26
+
+
+def kernel_mean_block_rows(n: int) -> int:
+    """Rows per block of ``kernel_means`` for a support of ``n`` rows."""
+    return max(1, min(n, KERNEL_MEAN_BLOCK_ELEMS // n))
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _kernel_means_blocks(spec: KernelSpec, rows: int, x: jax.Array,
+                         gamma: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    n, m = x.shape
+    blocks = -(-n // rows)
+    xb = jnp.pad(x, ((0, blocks * rows - n), (0, 0))).reshape(blocks, rows, m)
+
+    def row_sums(_, xi):
+        return None, jnp.sum(gram(spec, xi, x, gamma=gamma), axis=1)
+
+    _, sums = jax.lax.scan(row_sums, None, xb)
+    k_row_mean = sums.reshape(-1)[:n] / n      # the padded rows drop here
+    return k_row_mean, jnp.mean(k_row_mean)
+
+
+def kernel_means(spec: KernelSpec, x: jax.Array,
+                 gamma: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(m (N,), mu_bar ()): the kernel row means m_i = mean_t K(x_i, x_t)
+    and their grand mean, in one program over row blocks of ``x`` (see
+    ``KERNEL_MEAN_BLOCK_ELEMS``): O(rows * N) memory, never the (N, N)
+    Gram."""
+    n = x.shape[0]
+    rows = kernel_mean_block_rows(n)
+    with trace.span("oos.kernel_means", rows=n, block_rows=rows,
+                    blocks=-(-n // rows)):
+        return jax.block_until_ready(
+            _kernel_means_blocks(spec, rows, x, gamma))
+
+
 def from_dual(x_train: jax.Array, alpha: jax.Array, spec: KernelSpec,
               gamma: Optional[jax.Array] = None,
               center: bool = True) -> FittedKpca:
@@ -122,18 +164,17 @@ def from_dual(x_train: jax.Array, alpha: jax.Array, spec: KernelSpec,
     Returns:
       ``FittedKpca`` with coefs (N, C) float32.
 
-    For ``center=True`` the *uncentered* training Gram is formed once here
-    (fit-time cost) to extract the kernel mean statistics the centered score
-    needs; serving never touches the training Gram again.
+    For ``center=True`` the kernel mean statistics the centered score
+    needs are taken here (fit-time cost) by ``kernel_means``, in row
+    blocks of the uncentered training Gram; serving never touches the
+    training Gram again.
     """
     x_train = jnp.asarray(x_train)
     alpha = _as_2d(alpha).astype(jnp.float32)
     g = resolve_gamma(spec, x_train) if gamma is None else jnp.asarray(gamma)
     c = alpha.shape[1]
     if center:
-        k_raw = gram(spec, x_train, gamma=g)
-        m = jnp.mean(k_raw, axis=1)                       # (N,)
-        mu_bar = jnp.mean(k_raw)
+        m, mu_bar = kernel_means(spec, x_train, g)
         alpha_sum = jnp.sum(alpha, axis=0)                # (C,)
         row_mean_coef = -alpha_sum
         bias = mu_bar * alpha_sum - jnp.matmul(m, alpha, precision=HIGHEST)
@@ -368,6 +409,9 @@ def compress(model: FittedKpca, n_landmarks: int,
       (compressed model, rel_err (C,)) with
       rel_err_c = ||w_c - w_hat_c||_H / ||w_c||_H, exact (computed from the
       Pythagorean identity for the RKHS projection).
+
+    The error forms the support's whole (L, L) Gram, unlike ``from_dual``:
+    compression is an offline step, not on the serving path.
     """
     l_full = model.n_support
     if not 0 < n_landmarks <= l_full:
@@ -526,6 +570,10 @@ def shard_fitted(model: FittedKpca, n_shards: int,
       per-shard term is exact (Pythagorean identity) and the sum bounds the
       error of the summed component by the triangle inequality. Zeros when
       no compression is requested (sharding alone is exact).
+
+    With ``landmarks_per_shard`` set, the error bound forms the support's
+    whole (L, L) Gram, unlike ``from_dual``: an offline step, not on the
+    serving path.
     """
     l_full, c = model.n_support, model.n_components
     if not 0 < n_shards <= l_full:

@@ -71,6 +71,8 @@ class EngineStats:
     n_rejected: int = 0           # admissions refused (QueueFullError)
     n_shed: int = 0               # queued requests dropped to admit newer
     n_flushes: int = 0            # drain cycles that served >= 1 request
+    n_slabs: int = 0              # projection program calls of the
+    #                               drains (the start() warmup's excluded)
     n_retries: int = 0            # drain attempts retried after a fault
     n_deadline_expired: int = 0   # requests failed on the request deadline
     n_donated: int = 0            # dispatches through donated entry points

@@ -1011,6 +1011,8 @@ class KpcaEngine:
         else:
             xq = self._stage_slab(slab)
             out = self._proj_donated(model, xq)
+        with self._stats_lock:
+            self.stats.n_slabs += 1
         return out, time.perf_counter() - t0
 
 

@@ -447,3 +447,25 @@ class TestInlineDrains:
             assert names[d].count("serve.device") >= 1
             assert len(lines[d]) == 1, lines[d]
         assert any("serve.wait" in n for n in names.values())
+
+
+class TestSlabCount:
+    """``EngineStats.n_slabs`` counts projection program calls: one per
+    slab a drain dispatches, on the device-runner and the inline path."""
+
+    @pytest.mark.parametrize("inline", [False, True])
+    def test_a_drain_over_max_batch_rows_is_two_calls(self, engine, inline,
+                                                      monkeypatch):
+        monkeypatch.setattr(kpca_engine, "drains_inline",
+                            lambda platform: inline)
+        eng = engine(KpcaServeConfig(max_batch=128, min_bucket=8))
+        eng.start()
+        assert eng.stats.n_slabs == 0             # warmup calls not counted
+        eng.submit(_rand((192, 12), seed=600)).result(timeout=WAIT)
+        assert eng.stats.n_flushes == 1
+        assert eng.stats.n_slabs == 2                 # 128 + 64 rows
+        eng.submit(_rand((5, 12), seed=601)).result(timeout=WAIT)
+        eng.close()
+        assert eng.stats.n_slabs == 3                 # + one 8-row slab
+        assert eng.stats.n_queries + eng.stats.n_padded == 128 + 64 + 8
+        assert eng.stats.n_inline_drains == (2 if inline else 0)

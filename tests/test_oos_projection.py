@@ -87,6 +87,97 @@ class TestFittedKpca:
                                       np.asarray(oos.project(back, xq)))
 
 
+class TestBlockwiseKernelMeans:
+    """``from_dual(center=True)`` takes the kernel means in row blocks of
+    the training Gram, in one program that never holds the (L, L) Gram."""
+
+    @staticmethod
+    def _dense_stats(x, alpha, spec, gamma):
+        x = np.asarray(x, np.float64)
+        if spec.kind == "rbf":
+            sq = np.sum(x * x, 1)
+            d2 = np.maximum(sq[:, None] + sq[None, :] - 2.0 * x @ x.T, 0.0)
+            k = np.exp(-gamma * d2)
+        else:
+            k = x @ x.T * spec.scale
+            diag = np.diag(k).copy()
+            if spec.kind == "poly":
+                k = (k + spec.coef) ** spec.degree
+                diag = (diag + spec.coef) ** spec.degree
+            k = k / np.sqrt(diag[:, None] * diag[None, :])
+        m = k.mean(axis=1)
+        mu = k.mean()
+        a = np.asarray(alpha, np.float64)
+        return {"k_row_mean": m, "k_grand_mean": mu,
+                "bias": mu * a.sum(0) - m @ a, "row_mean_coef": -a.sum(0)}
+
+    @pytest.mark.parametrize("kind", ["rbf", "linear", "poly"])
+    def test_forced_small_blocks_match_the_dense_float64_stats(
+            self, kind, monkeypatch):
+        n = 1000
+        monkeypatch.setattr(oos, "KERNEL_MEAN_BLOCK_ELEMS", 96 * n)
+        assert oos.kernel_mean_block_rows(n) == 96       # 10 blocks + 40
+        x = np.random.default_rng(3).uniform(size=(n, 24)).astype(np.float32)
+        alpha = _rand((n, 2), seed=4) / np.sqrt(n)
+        spec = KernelSpec(kind=kind, degree=2)
+        model = oos.from_dual(jnp.asarray(x), jnp.asarray(alpha), spec)
+        want = self._dense_stats(x, alpha, spec, float(model.gamma))
+        for name, ref in want.items():
+            got = np.asarray(getattr(model, name), np.float64)
+            err = np.max(np.abs(got - ref)) / np.max(np.abs(ref))
+            assert err < 1e-5, (name, err)
+
+    @staticmethod
+    def _largest_value(jaxpr) -> int:
+        """Elements of the largest value any equation of ``jaxpr`` (and
+        of every program nested in it) makes."""
+        import jax
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+        biggest = 0
+        for eqn in jaxpr.eqns:
+            for v in eqn.outvars:
+                biggest = max(biggest, int(np.prod(v.aval.shape)))
+            for p in jax.tree_util.tree_leaves(
+                    list(eqn.params.values()),
+                    is_leaf=lambda t: isinstance(t, (Jaxpr, ClosedJaxpr))):
+                if isinstance(p, ClosedJaxpr):
+                    p = p.jaxpr
+                if isinstance(p, Jaxpr):
+                    biggest = max(biggest,
+                                  TestBlockwiseKernelMeans._largest_value(p))
+        return biggest
+
+    @pytest.mark.parametrize("n,budget", [(4096, 2 ** 20),
+                                          (60000, None)])
+    def test_program_holds_no_support_by_support_array(self, n, budget,
+                                                       monkeypatch):
+        import jax
+        if budget is not None:
+            monkeypatch.setattr(oos, "KERNEL_MEAN_BLOCK_ELEMS", budget)
+        rows = oos.kernel_mean_block_rows(n)
+        assert 1 < rows < n
+        closed = jax.make_jaxpr(
+            lambda x, g: oos._kernel_means_blocks(SPEC, rows, x, g))(
+            jax.ShapeDtypeStruct((n, 784), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+        biggest = self._largest_value(closed.jaxpr)
+        assert rows * n <= biggest < n * n            # the block's tile
+
+    def test_the_pass_is_one_span(self):
+        from repro.obs import trace
+        tracer = trace.Tracer()
+        outer = trace.active()
+        trace.install(tracer)
+        try:
+            oos.from_dual(jnp.asarray(_rand((50, 8), seed=5)),
+                          jnp.asarray(_rand((50,), seed=6)), SPEC)
+        finally:
+            trace.install(outer)
+        spans = [attrs for ph, name, _t0, _dur, _tid, attrs
+                 in tracer.events() if name == "oos.kernel_means"]
+        assert spans == [{"rows": 50, "block_rows": 50, "blocks": 1}]
+
+
 class TestCompression:
     def test_error_monotone_in_landmarks(self, fitted):
         """Nested landmark sets => RKHS reconstruction error is monotone
