@@ -54,10 +54,15 @@ def _dot_t(x: jax.Array, y: jax.Array) -> jax.Array:
     return jnp.matmul(x, y.T, precision=HIGHEST)
 
 
-def pairwise_sqdist(x: jax.Array, y: jax.Array) -> jax.Array:
-    """Squared euclidean distances. x: (n, m), y: (k, m) -> (n, k)."""
+def pairwise_sqdist(x: jax.Array, y: jax.Array,
+                    y_sq: Optional[jax.Array] = None) -> jax.Array:
+    """Squared euclidean distances. x: (n, m), y: (k, m) -> (n, k).
+
+    y_sq: optional (k,) precomputed ``sum(y * y, -1)``; a caller that holds
+    it (the served artifact's support norms) saves the pass over ``y``.
+    """
     sx = jnp.sum(x * x, axis=-1)
-    sy = jnp.sum(y * y, axis=-1)
+    sy = jnp.sum(y * y, axis=-1) if y_sq is None else y_sq
     d2 = sx[:, None] + sy[None, :] - 2.0 * _dot_t(x, y)
     return jnp.maximum(d2, 0.0)
 
@@ -73,25 +78,31 @@ def resolve_gamma(spec: KernelSpec, x: jax.Array) -> jax.Array:
 
 
 def gram(spec: KernelSpec, x: jax.Array, y: Optional[jax.Array] = None,
-         gamma: Optional[jax.Array] = None) -> jax.Array:
-    """Dense Gram matrix K[i, j] = K(x_i, y_j). Pure-jnp oracle."""
+         gamma: Optional[jax.Array] = None,
+         y_sq: Optional[jax.Array] = None) -> jax.Array:
+    """Dense Gram matrix K[i, j] = K(x_i, y_j). Pure-jnp oracle.
+
+    y_sq: optional (k,) precomputed squared row norms of ``y`` (see
+    ``pairwise_sqdist``); every kernel kind derives its ``y`` terms from it.
+    """
     if y is None:
         y = x
     if spec.kind == "rbf":
         g = resolve_gamma(spec, x) if gamma is None else gamma
-        return jnp.exp(-g * pairwise_sqdist(x, y))
+        return jnp.exp(-g * pairwise_sqdist(x, y, y_sq))
     k = _dot_t(x, y) * spec.scale
     if spec.kind == "poly":
         k = (k + spec.coef) ** spec.degree
     if spec.normalize:
         dx = _self_k(spec, x)
-        dy = _self_k(spec, y)
+        dy = _self_k(spec, y, y_sq)
         k = k / jnp.sqrt(jnp.maximum(dx[:, None] * dy[None, :], 1e-12))
     return k
 
 
-def _self_k(spec: KernelSpec, x: jax.Array) -> jax.Array:
-    s = jnp.sum(x * x, axis=-1) * spec.scale
+def _self_k(spec: KernelSpec, x: jax.Array,
+            x_sq: Optional[jax.Array] = None) -> jax.Array:
+    s = (jnp.sum(x * x, axis=-1) if x_sq is None else x_sq) * spec.scale
     if spec.kind == "poly":
         s = (s + spec.coef) ** spec.degree
     return s

@@ -58,6 +58,10 @@ class FittedKpca:
     bias:          (C,) constant score offset (``mu_bar sum_i alpha_i
                    - m . alpha`` for a centered fit; 0 otherwise).
     gamma:         () resolved RBF bandwidth actually used at fit time.
+    support_sq_norm: (L,) squared row norms ``sum(x_support**2, -1)``,
+                   taken once when the artifact is built
+                   (``support_sq_norms``) so no projection call re-reads
+                   the support for them.
     k_row_mean:    optional (L,) cached kernel mean statistics
                    m_i = mean_t K(x_i, t) over the training set — kept so
                    ``refresh_coefficients`` can rebuild the centering terms
@@ -72,6 +76,7 @@ class FittedKpca:
     row_mean_coef: jax.Array
     bias: jax.Array
     gamma: jax.Array
+    support_sq_norm: jax.Array
     k_row_mean: Optional[jax.Array] = None
     k_grand_mean: Optional[jax.Array] = None
     spec: KernelSpec = KernelSpec()
@@ -91,7 +96,7 @@ class FittedKpca:
 
 def _flatten(m: FittedKpca):
     return ((m.x_support, m.coefs, m.row_mean_coef, m.bias, m.gamma,
-             m.k_row_mean, m.k_grand_mean), m.spec)
+             m.support_sq_norm, m.k_row_mean, m.k_grand_mean), m.spec)
 
 
 def _unflatten(spec, leaves):
@@ -146,6 +151,18 @@ def kernel_means(spec: KernelSpec, x: jax.Array,
             _kernel_means_blocks(spec, rows, x, gamma))
 
 
+@jax.jit
+def _sq_norms(x: jax.Array) -> jax.Array:
+    return jnp.sum(x * x, axis=-1)
+
+
+def support_sq_norms(x: jax.Array) -> jax.Array:
+    """(L,) squared row norms of a support set: the artifact's
+    ``support_sq_norm``, one pass over ``x`` per artifact built."""
+    with trace.span("oos.support_norms", rows=x.shape[0]):
+        return jax.block_until_ready(_sq_norms(x))
+
+
 def from_dual(x_train: jax.Array, alpha: jax.Array, spec: KernelSpec,
               gamma: Optional[jax.Array] = None,
               center: bool = True) -> FittedKpca:
@@ -185,7 +202,9 @@ def from_dual(x_train: jax.Array, alpha: jax.Array, spec: KernelSpec,
         stats = {}
     return FittedKpca(x_support=x_train, coefs=alpha,
                       row_mean_coef=row_mean_coef, bias=bias,
-                      gamma=g.astype(jnp.float32), spec=spec, **stats)
+                      gamma=g.astype(jnp.float32),
+                      support_sq_norm=support_sq_norms(x_train), spec=spec,
+                      **stats)
 
 
 def fit_central(x: jax.Array, spec: KernelSpec, n_components: int = 1,
@@ -268,11 +287,11 @@ def refresh_coefficients(model: Union[FittedKpca, "ShardedFittedKpca"],
     (``repro.serve.publisher.ModelHandle``) without ever re-forming the
     training Gram.
 
-    The support set, bandwidth and kernel spec are reused as-is; the
-    centering terms (row_mean_coef, bias) are recomputed from the CACHED
-    kernel mean statistics (``k_row_mean``/``k_grand_mean``, recorded at
-    fit time by ``from_dual(center=True)``) — an O(L*C) update instead of
-    the O(L^2) Gram pass. A ``ShardedFittedKpca`` refreshes the same way
+    The support set, its norms, bandwidth and kernel spec are reused
+    as-is; the centering terms (row_mean_coef, bias) are recomputed from
+    the CACHED kernel mean statistics (``k_row_mean``/``k_grand_mean``,
+    recorded at fit time by ``from_dual(center=True)``) — an O(L*C)
+    update instead of the O(L^2) Gram pass. A ``ShardedFittedKpca`` refreshes the same way
     per shard: each shard's coefficient rows are swapped against its own
     cached kernel-mean slice and the GLOBAL centering terms are rebuilt
     from the per-shard partial sums (see also
@@ -317,7 +336,9 @@ def refresh_coefficients(model: Union[FittedKpca, "ShardedFittedKpca"],
         bias = jnp.zeros((c,), jnp.float32)
     return FittedKpca(x_support=model.x_support, coefs=alpha,
                       row_mean_coef=row_mean_coef, bias=bias,
-                      gamma=model.gamma, k_row_mean=model.k_row_mean,
+                      gamma=model.gamma,
+                      support_sq_norm=model.support_sq_norm,
+                      k_row_mean=model.k_row_mean,
                       k_grand_mean=model.k_grand_mean, spec=model.spec)
 
 
@@ -345,7 +366,8 @@ def project(model: FittedKpca, x_query: jax.Array,
         return project_op(model.spec, x_query, model.x_support, model.coefs,
                           row_mean_coef=model.row_mean_coef, bias=model.bias,
                           gamma=model.gamma, interpret=interpret)
-    k = gram(model.spec, x_query, model.x_support, gamma=model.gamma)
+    k = gram(model.spec, x_query, model.x_support, gamma=model.gamma,
+             y_sq=model.support_sq_norm)
     return (jnp.matmul(k, model.coefs, precision=HIGHEST)
             + jnp.mean(k, axis=1, keepdims=True) * model.row_mean_coef[None]
             + model.bias[None, :])
@@ -428,7 +450,9 @@ def compress(model: FittedKpca, n_landmarks: int,
     compressed = FittedKpca(
         x_support=z, coefs=beta,
         row_mean_coef=jnp.zeros_like(model.row_mean_coef),
-        bias=model.bias, gamma=model.gamma, spec=model.spec)
+        bias=model.bias, gamma=model.gamma,
+        support_sq_norm=model.support_sq_norm[jnp.asarray(idx)],
+        spec=model.spec)
     return compressed, rel_err
 
 
@@ -809,7 +833,9 @@ def gather_fitted(sharded: ShardedFittedKpca) -> FittedKpca:
             k_grand_mean=sharded.k_grand_mean)
     return FittedKpca(x_support=xs, coefs=coefs,
                       row_mean_coef=sharded.row_mean_coef, bias=sharded.bias,
-                      gamma=sharded.gamma, spec=sharded.spec, **stats)
+                      gamma=sharded.gamma,
+                      support_sq_norm=support_sq_norms(xs), spec=sharded.spec,
+                      **stats)
 
 
 # ---- persistence (repro.checkpoint layout) --------------------------------
@@ -833,7 +859,8 @@ def save_fitted(ckpt_dir: str, model: FittedKpca) -> str:
 
 
 def load_fitted(ckpt_dir: str) -> FittedKpca:
-    """Restore a ``save_fitted`` checkpoint; validates the artifact kind."""
+    """Restore a ``save_fitted`` checkpoint; validates the artifact kind.
+    The support norms are not stored: they are taken again here."""
     from ..checkpoint import restore_checkpoint
     tree, meta, _ = restore_checkpoint(ckpt_dir)
     if meta.get("kind") != "fitted_kpca":
@@ -842,6 +869,7 @@ def load_fitted(ckpt_dir: str) -> FittedKpca:
     return FittedKpca(x_support=tree["x_support"], coefs=tree["coefs"],
                       row_mean_coef=tree["row_mean_coef"],
                       bias=tree["bias"], gamma=tree["gamma"],
+                      support_sq_norm=support_sq_norms(tree["x_support"]),
                       k_row_mean=tree.get("k_row_mean"),
                       k_grand_mean=tree.get("k_grand_mean"), spec=spec)
 

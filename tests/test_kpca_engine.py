@@ -66,6 +66,38 @@ class TestEngineCorrectness:
         with pytest.raises(ValueError):
             eng.placed_devices("mp")
 
+    def test_serving_program_reads_no_support_norms(self):
+        """The projection program takes the support's norms from the
+        artifact: no reduce runs over the (L, M) support. L=300 is no
+        slab's row count, so the support's shape names it alone."""
+        xs = jnp.asarray(_rand((300, 12), seed=30))
+        m = oos.from_dual(xs, jnp.asarray(_rand((300, 2), seed=31)), SPEC)
+        eng = KpcaEngine(m, KpcaServeConfig(max_batch=16, min_bucket=4))
+        slab = jnp.zeros((16, 12), jnp.float32)
+        text = eng._proj.lower(m, slab).as_text()
+        reduces = [ln for ln in text.splitlines() if "stablehlo.reduce" in ln]
+        assert any("tensor<16x12xf32>" in ln for ln in reduces)  # query norms
+        assert not any("tensor<300x12xf32>" in ln for ln in reduces)
+
+    def test_support_norms_span_once_per_artifact(self):
+        from repro.obs import trace
+        tracer = trace.Tracer()
+        outer = trace.active()
+        trace.install(tracer)
+        try:
+            m = oos.from_dual(jnp.asarray(_rand((40, 12), seed=32)),
+                              jnp.asarray(_rand((40, 2), seed=33)), SPEC)
+            eng = KpcaEngine(m, KpcaServeConfig(max_batch=8, min_bucket=4))
+            eng.project_many([_rand((q, 12), seed=34 + q)
+                              for q in (1, 5, 8, 13)])
+        finally:
+            trace.install(outer)
+        names = [name for _ph, name, *_ in tracer.events()]
+        assert any(n.startswith("serve.") for n in names)  # drains traced
+        spans = [attrs for _ph, name, _t0, _dur, _tid, attrs
+                 in tracer.events() if name == "oos.support_norms"]
+        assert spans == [{"rows": 40}]
+
     def test_interleaved_submit_flush(self, model):
         eng = KpcaEngine(model, KpcaServeConfig(max_batch=16, min_bucket=4))
         r1 = eng.submit(_rand((5, 12), seed=1))

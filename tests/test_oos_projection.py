@@ -178,6 +178,54 @@ class TestBlockwiseKernelMeans:
         assert spans == [{"rows": 50, "block_rows": 50, "blocks": 1}]
 
 
+class TestSupportNorms:
+    """The artifact carries its support's squared row norms, taken once
+    when it is built, and ``project`` reads them instead of the support."""
+
+    @staticmethod
+    def _built(how, fitted, tmp_path):
+        _, model = fitted
+        if how == "from_dual":
+            return model
+        if how == "refresh_coefficients":
+            return oos.refresh_coefficients(model, model.coefs * 2.0)
+        if how == "compress":
+            return oos.compress(model, 20, seed=1)[0]
+        if how == "gather_fitted":
+            return oos.gather_fitted(oos.shard_fitted(model, 3)[0])
+        oos.save_fitted(str(tmp_path / "ck"), model)
+        return oos.load_fitted(str(tmp_path / "ck"))
+
+    @pytest.mark.parametrize("how", ["from_dual", "refresh_coefficients",
+                                     "compress", "gather_fitted",
+                                     "save_load"])
+    def test_every_constructor_sets_the_norms(self, how, fitted, tmp_path):
+        model = self._built(how, fitted, tmp_path)
+        xs = jnp.asarray(model.x_support)
+        assert model.support_sq_norm.shape == (model.n_support,)
+        assert model.support_sq_norm.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(model.support_sq_norm),
+                                   np.asarray(jnp.sum(xs * xs, -1)),
+                                   rtol=1e-6)
+
+    @pytest.mark.parametrize("kind", ["rbf", "linear", "poly"])
+    def test_scores_match_the_gram_oracle(self, kind):
+        import jax
+        spec = KernelSpec(kind=kind, gamma=0.2, degree=2, coef=0.5,
+                          scale=0.3)
+        xs = jnp.asarray(_rand((70, 10), seed=20))
+        xq = jnp.asarray(_rand((9, 10), seed=21))
+        model = oos.from_dual(xs, jnp.asarray(_rand((70, 2), seed=22)), spec)
+        with jax.default_matmul_precision("highest"):
+            k = gram(spec, xq, xs, gamma=model.gamma)
+            want = (k @ model.coefs
+                    + jnp.mean(k, axis=1, keepdims=True)
+                    * model.row_mean_coef[None] + model.bias[None])
+            got = oos.project(model, xq)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
+
 class TestCompression:
     def test_error_monotone_in_landmarks(self, fitted):
         """Nested landmark sets => RKHS reconstruction error is monotone
